@@ -78,6 +78,7 @@ import numpy as np
 
 from repro.core.estimator import LayerShape, time_naive, time_ours
 from repro.graph import NeighborSampler, make_dataset
+from repro.launch.mesh import forced_host_env, make_mesh
 from repro.graph.datasets import DATASET_STATS
 from repro.models.gcn_model import GCNConfig, gcn_loss, init_gcn_params
 from repro.optim import apply_updates, sgd
@@ -249,7 +250,7 @@ def measured_overlap(n_cores: int = 8, batch: int = 512, mid: int = 2048,
         raise RuntimeError(
             f"need {n_cores} devices, have {len(jax.devices())} — set "
             "XLA_FLAGS=--xla_force_host_platform_device_count")
-    mesh = jax.make_mesh((n_cores,), ("model",))
+    mesh = make_mesh((n_cores,), ("model",))
     # canonicalize first (' ell' / bare 'ell' → 'ell+pipelined') so the
     # legacy-key mapping, dedupe, and the oracle filter all see one
     # spelling; the oracle always runs — listing it as an arm would only
@@ -349,7 +350,7 @@ def _measured_overlap_aggregate_op(n_cores: int, n_dst: int, n_src: int,
     coo = from_edges(rng.integers(0, n_dst, e), rng.integers(0, n_src, e),
                      np.abs(rng.standard_normal(e)).astype(np.float32) + 0.1,
                      n_dst, n_src)
-    mesh = jax.make_mesh((n_cores,), ("model",))
+    mesh = make_mesh((n_cores,), ("model",))
     x = leading_axis_put(mesh,
                          rng.standard_normal((n_src, d)).astype(np.float32))
     ser = Engine("coo+serial").build(mesh, graph=coo).aggregator()
@@ -404,12 +405,8 @@ def run_overlap_arm(n_cores: int = 8, *, smoke: bool = False,
         "from benchmarks.epoch_time import measured_overlap;"
         f"print(json.dumps(measured_overlap(**{kwargs!r})))"
     )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_cores} "
-                        + env.get("XLA_FLAGS", "")).strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
+    env = forced_host_env(n_cores, os.path.join(root, "src"))
     proc = subprocess.run([sys.executable, "-c", child], env=env, cwd=root,
                           capture_output=True, text=True, timeout=1200)
     if proc.returncode != 0:
@@ -527,7 +524,7 @@ def measured_redundancy(n_cores: int = 4, batch: int = 256, mid: int = 1024,
         raise RuntimeError(
             f"need {n_cores} devices, have {len(jax.devices())} — set "
             "XLA_FLAGS=--xla_force_host_platform_device_count")
-    mesh = jax.make_mesh((n_cores,), ("model",))
+    mesh = make_mesh((n_cores,), ("model",))
     layers = _synthetic_powerlaw_layers(batch, mid, frontier, deg, n_cores,
                                         seed)
     rng = np.random.default_rng(seed + 1)
@@ -597,12 +594,8 @@ def run_redundancy_arm(n_cores: int = 4, *, smoke: bool = False,
         "from benchmarks.epoch_time import measured_redundancy;"
         f"print(json.dumps(measured_redundancy(**{kwargs!r})))"
     )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_cores} "
-                        + env.get("XLA_FLAGS", "")).strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
+    env = forced_host_env(n_cores, os.path.join(root, "src"))
     proc = subprocess.run([sys.executable, "-c", child], env=env, cwd=root,
                           capture_output=True, text=True, timeout=1200)
     if proc.returncode != 0:
@@ -661,7 +654,7 @@ def measured_topologies(n_cores: int = 4, base_spec: str = "ell+pipelined",
     topologies = sorted(s[len(prefix):]
                         for s in supported_specs(three_part=True)
                         if s.startswith(prefix))
-    mesh = jax.make_mesh((n_cores,), ("model",))
+    mesh = make_mesh((n_cores,), ("model",))
     layers = _synthetic_layers(batch, mid, frontier, deg, seed)
     out: Dict = {"n_cores": n_cores, "backend": jax.default_backend(),
                  "base_spec": f"{base.format}+"
@@ -755,7 +748,7 @@ def _measured_topology_aggregate_op(n_cores: int, n_dst: int, n_src: int,
     coo = from_edges(rng.integers(0, n_dst, e), rng.integers(0, n_src, e),
                      np.abs(rng.standard_normal(e)).astype(np.float32) + 0.1,
                      n_dst, n_src)
-    mesh = jax.make_mesh((n_cores,), ("model",))
+    mesh = make_mesh((n_cores,), ("model",))
     x = leading_axis_put(mesh,
                          rng.standard_normal((n_src, d)).astype(np.float32))
 
@@ -809,12 +802,8 @@ def run_topology_arm(n_cores: int = 4, *, smoke: bool = False,
         "from benchmarks.epoch_time import measured_topologies;"
         f"print(json.dumps(measured_topologies(**{kwargs!r})))"
     )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_cores} "
-                        + env.get("XLA_FLAGS", "")).strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
+    env = forced_host_env(n_cores, os.path.join(root, "src"))
     proc = subprocess.run([sys.executable, "-c", child], env=env, cwd=root,
                           capture_output=True, text=True, timeout=1200)
     if proc.returncode != 0:
@@ -892,7 +881,7 @@ def measured_auto(n_cores: int = 4, batch: int = 256, mid: int = 512,
                  "resolved_matches_winner":
                      canon(resolved) == canon(entry["spec"]),
                  "autotune_s_per_step": entry["s_per_step"]}
-    mesh = jax.make_mesh((n_cores,), ("model",))
+    mesh = make_mesh((n_cores,), ("model",))
     layers = _synthetic_layers(batch, mid, frontier, deg, seed)
     runs = {}
     for name, spec in (("manual", entry["spec"]), ("auto", "auto")):
@@ -941,12 +930,8 @@ def run_auto_arm(n_cores: int = 4, *, smoke: bool = False,
         "from benchmarks.epoch_time import measured_auto;"
         f"print(json.dumps(measured_auto(**{kwargs!r})))"
     )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_cores} "
-                        + env.get("XLA_FLAGS", "")).strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
+    env = forced_host_env(n_cores, os.path.join(root, "src"))
     proc = subprocess.run([sys.executable, "-c", child], env=env, cwd=root,
                           capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
@@ -1045,12 +1030,8 @@ def run_input_pipeline_arm(n_cores: int = 4, *, smoke: bool = False,
         "from benchmarks.epoch_time import measured_input_pipeline;"
         f"print(json.dumps(measured_input_pipeline(**{kwargs!r})))"
     )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_cores} "
-                        + env.get("XLA_FLAGS", "")).strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
+    env = forced_host_env(n_cores, os.path.join(root, "src"))
     proc = subprocess.run([sys.executable, "-c", child], env=env, cwd=root,
                           capture_output=True, text=True, timeout=1200)
     if proc.returncode != 0:
@@ -1185,12 +1166,8 @@ def run_feature_store_arm(n_cores: int = 4, *, smoke: bool = False,
         "from benchmarks.epoch_time import measured_feature_store;"
         f"print(json.dumps(measured_feature_store(**{kwargs!r})))"
     )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_cores} "
-                        + env.get("XLA_FLAGS", "")).strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
+    env = forced_host_env(n_cores, os.path.join(root, "src"))
     proc = subprocess.run([sys.executable, "-c", child], env=env, cwd=root,
                           capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:

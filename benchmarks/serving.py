@@ -146,6 +146,8 @@ def run_serving_arm(n_cores: int = 4, *, smoke: bool = False,
     """Re-exec :func:`measured_serving` under a forced multi-device
     backend and write ``out_path`` (same child-process pattern as the
     other arms: XLA_FLAGS must precede the jax import)."""
+    from repro.launch.mesh import forced_host_env
+
     kwargs: Dict = {"n_cores": n_cores}
     if smoke:
         # rate/SLO sized to stress the arms apart on a CI host: the cold
@@ -160,12 +162,8 @@ def run_serving_arm(n_cores: int = 4, *, smoke: bool = False,
         "from benchmarks.serving import measured_serving;"
         f"print(json.dumps(measured_serving(**{kwargs!r})))"
     )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_cores} "
-                        + env.get("XLA_FLAGS", "")).strip()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
+    env = forced_host_env(n_cores, os.path.join(root, "src"))
     proc = subprocess.run([sys.executable, "-c", child], env=env, cwd=root,
                           capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:

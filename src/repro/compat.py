@@ -1,83 +1,15 @@
-"""Version-compat shims for the jax API surface this repo targets.
+"""One import point for the JAX mesh API this repo uses (JAX ≥ 0.9).
 
-The codebase is written against the modern spelling (``jax.shard_map``,
-``jax.set_mesh``); older jaxlib builds (e.g. the 0.4.3x CPU wheels this
-container ships) only expose ``jax.experimental.shard_map.shard_map`` and
-have no context-mesh setter at all.  Importing from here gives every module
-and test one spelling that works on both:
-
-  * :func:`shard_map` — ``jax.shard_map`` when present, else the
-    experimental entry point wrapped so ``mesh`` may be omitted and picked
-    up from the innermost :func:`set_mesh` context.
-  * :func:`set_mesh` — ``jax.set_mesh`` when present, else a context
-    manager that records the mesh for :func:`shard_map` and enters the
-    legacy ``Mesh`` resource context (so pjit specs keep resolving).
+* :func:`shard_map` — ``jax.shard_map``; ``mesh`` may be omitted inside a
+  :func:`set_mesh` context.
+* :func:`set_mesh` — ``jax.set_mesh``.
+* :func:`axis_size` — ``jax.lax.axis_size``: static size of a named mesh
+  axis from inside ``shard_map``.
 """
 from __future__ import annotations
 
-import contextlib
-import threading
-from typing import Any, Optional
-
 import jax
 
-_local = threading.local()
-
-
-def _context_mesh() -> Optional[Any]:
-    stack = getattr(_local, "mesh_stack", None)
-    return stack[-1] if stack else None
-
-
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh=None, in_specs=None, out_specs=None, **kwargs):
-        if mesh is None:
-            mesh = _context_mesh()
-        if mesh is None:
-            return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                                 **kwargs)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh=None, in_specs=None, out_specs=None, **kwargs):
-        if mesh is None:
-            mesh = _context_mesh()
-        if mesh is None:
-            raise ValueError(
-                "shard_map needs a mesh: pass mesh= or enter "
-                "repro.compat.set_mesh(mesh)")
-        kwargs.pop("axis_names", None)  # new-API-only knob, default is fine
-        return _shard_map_exp(f, mesh, in_specs, out_specs, **kwargs)
-
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-else:
-    @contextlib.contextmanager
-    def set_mesh(mesh):
-        stack = getattr(_local, "mesh_stack", None)
-        if stack is None:
-            stack = _local.mesh_stack = []
-        stack.append(mesh)
-        try:
-            # legacy resource context: lets pjit resolve PartitionSpecs
-            with mesh:
-                yield mesh
-        finally:
-            stack.pop()
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis from inside shard_map.
-
-    ``jax.lax.axis_size`` where it exists; on older jax ``psum(1, axis)``
-    constant-folds to a python int under shard_map, which is all callers
-    need (sizes feed shapes and denominators).
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
+shard_map = jax.shard_map
+set_mesh = jax.set_mesh
+axis_size = jax.lax.axis_size

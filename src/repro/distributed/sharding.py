@@ -18,7 +18,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 MODEL = "model"
 DATA = "data"
@@ -103,6 +103,21 @@ def gcn_weights(mesh: Mesh) -> P:
 
 def named(mesh: Mesh, spec: P) -> NamedSharding:
     return NamedSharding(mesh, spec)
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis typed ``AxisType.Auto``.
+
+    ``jax.make_mesh`` types its axes ``Explicit`` by default, which makes
+    every array the engine returns carry its sharding in the type: eager
+    ops and ``jax.grad`` taken on those outputs outside a ``jax.set_mesh``
+    context then fail to match the mesh size.  The engine places and
+    shards everything itself, so it runs on the same devices with the
+    axes left to the compiler.
+    """
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def leading_axis_spec(a, axis: str = MODEL) -> P:

@@ -149,7 +149,13 @@ class Engine:
         use).  ``graph`` pre-binds a default COO for ``aggregate``.  An
         explicit ``n_cores`` overrides the mesh-derived core count (shard
         shapes vs placement mesh — a mismatch fails loudly at step time).
+        The bundle runs on ``mesh`` with its axes retyped ``Auto``
+        (:func:`repro.distributed.sharding.auto_axes`), so the default
+        ``jax.make_mesh`` mesh works outside any ``jax.set_mesh`` context.
         """
+        if mesh is not None:
+            from repro.distributed.sharding import auto_axes
+            mesh = auto_axes(mesh)
         if n_cores is None:
             if mesh is None:
                 raise ValueError("Engine.build needs a mesh or n_cores")
@@ -365,6 +371,21 @@ class EngineBundle:
                 h = jnp.maximum(h, 0.0)
         return h                                       # [batch/P, classes]
 
+    def _shard_map(self, body, in_specs, out_specs):
+        """``shard_map`` over the bundle's mesh, built when the caller
+        traces it: inside a ``jax.set_mesh`` context of the same shape
+        (e.g. the user's default ``Explicit`` mesh) the axes take the
+        context's types, which ``shard_map`` requires to match; elsewhere
+        they stay ``Auto``."""
+        def call(*args):
+            mesh = self.mesh
+            ctx = jax.sharding.get_abstract_mesh()
+            if not ctx.empty and ctx.shape_tuple == mesh.shape_tuple:
+                mesh = mesh.update(axis_types=ctx.axis_types)
+            return shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs)(*args)
+        return call
+
     def _require_mesh(self, what: str) -> Mesh:
         if self.mesh is None:
             raise ValueError(f"{what} needs a mesh — rebuild with "
@@ -391,7 +412,7 @@ class EngineBundle:
         step = self._steps.get(dims)
         if step is not None:
             return step
-        mesh = self._require_mesh("train_step")
+        self._require_mesh("train_step")
         axis, lr = self.axis, self.config.lr
 
         def body(params, edges, x_local, labels_local):
@@ -411,8 +432,8 @@ class EngineBundle:
             return params, loss
 
         def step(params, batch):
-            fn = shard_map(
-                body, mesh=mesh,
+            fn = self._shard_map(
+                body,
                 in_specs=(P(), self._edge_specs(batch["edges"]),
                           P(axis, None), P(axis)),
                 out_specs=(P(), P()))
@@ -434,15 +455,15 @@ class EngineBundle:
         fwd = self._forwards.get(dims)
         if fwd is not None:
             return fwd
-        mesh = self._require_mesh("forward")
+        self._require_mesh("forward")
         axis = self.axis
 
         def body(params, edges, x_local):
             return self._forward_local(params, edges, dims, x_local)
 
         def fwd(params, batch):
-            fn = shard_map(
-                body, mesh=mesh,
+            fn = self._shard_map(
+                body,
                 in_specs=(P(), self._edge_specs(batch["edges"]),
                           P(axis, None)),
                 out_specs=P(axis, None))
@@ -508,9 +529,8 @@ class EngineBundle:
                 edge_leaves, x_local, self.n_chunks,
                 topology=self.config.topology)
 
-        fn = shard_map(
-            body, mesh=mesh,
-            in_specs=(self._edge_specs(leaves), P(self.axis, None)),
+        fn = self._shard_map(
+            body, in_specs=(self._edge_specs(leaves), P(self.axis, None)),
             out_specs=P(self.axis, None))
         if perm is None:
             return jax.jit(lambda x: fn(leaves, x))

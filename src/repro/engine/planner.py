@@ -491,6 +491,7 @@ def _autotune_measure(stats_kw: Optional[Dict], n_cores: int,
 
     from repro.distributed.gcn_train import init_params
     from repro.graph.coo import from_edges
+    from repro.launch.mesh import make_mesh
 
     from .engine import Engine
 
@@ -507,7 +508,7 @@ def _autotune_measure(stats_kw: Optional[Dict], n_cores: int,
         mid, frontier, deg, feat = 256, 512, 8, 64
     batch = _round_up(mid // 2, n_cores)
     hidden = feat
-    mesh = jax.make_mesh((n_cores,), ("model",))
+    mesh = make_mesh((n_cores,), ("model",))
     rng = np.random.default_rng(seed)
 
     def layer(n_dst, n_src):
@@ -567,12 +568,11 @@ def _autotune_measure_child(stats_kw: Optional[Dict], n_cores: int,
         f"print(json.dumps(_autotune_measure({stats_kw!r}, {n_cores!r}, "
         f"{list(candidates)!r}, {n_steps!r}, {n_trials!r}, {seed!r})))"
     )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_cores} "
-                        + env.get("XLA_FLAGS", "")).strip()
+    from repro.launch.mesh import forced_host_env
+
     src = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env = forced_host_env(n_cores, src)
     proc = subprocess.run([sys.executable, "-c", child], env=env,
                           capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:

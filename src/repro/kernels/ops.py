@@ -137,6 +137,9 @@ def spmm_ell(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray, *,
     xp = _pad_to(_pad_to(xz, 0, bs), 1, bd)
     cp = _pad_to(cols, 0, br, value=n_src)   # pad rows → all-zero-row entries
     vp = _pad_to(vals, 0, br)
+    if cp.shape[1] > _spmm.ELL_BK:           # hub bucket: whole degree slices
+        cp = _pad_to(cp, 1, _spmm.ELL_BK, value=n_src)
+        vp = _pad_to(vp, 1, _spmm.ELL_BK)
     out = _spmm.spmm_ell(cp, vp, xp, br=br, bd=bd, bs=bs,
                          interpret=not _on_tpu())
     return out[:nb, :d]

@@ -48,6 +48,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+#: Every kernel matmul runs at full f32 precision: Mosaic's default for f32
+#: operands is one bf16 pass, which put the ELL kernel 2.5e-3 (relative)
+#: off a float32 reference on a TPU v5e — outside the engine's fp32
+#: contract (``EngineConfig.precision``).
+_F32 = jax.lax.Precision.HIGHEST
+
+
+def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A kernel's output type: varying over every mesh axis an operand
+    varies over, so the call type-checks inside ``shard_map`` (whose
+    ``check_vma`` needs it); outside one the set is empty."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
 # ---------------------------------------------------------------------------
 # COO family (legacy reference arm) — one kernel body, two grid layouts.
 # ---------------------------------------------------------------------------
@@ -75,12 +90,14 @@ def _spmm_coo_kernel(rows_ref, cols_ref, vals_ref, x_ref, o_ref, acc_ref, *,
     # and gather nothing at all.
     src_iota = jax.lax.broadcasted_iota(jnp.int32, (be, n_src), 1)
     onehot_src = (src_iota == cols[:, None]).astype(x.dtype)
-    g = jnp.dot(onehot_src, x, preferred_element_type=jnp.float32)
+    g = jnp.dot(onehot_src, x, precision=_F32,
+                preferred_element_type=jnp.float32)
 
     # scatter-add via one-hot matmul, edge weights folded into the one-hot
     dst_iota = jax.lax.broadcasted_iota(jnp.int32, (n_rows, be), 0)
     onehot_dst = jnp.where(dst_iota == rows[None, :], vals[None, :], 0.0)
     acc_ref[...] += jnp.dot(onehot_dst.astype(jnp.float32), g,
+                            precision=_F32,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(edge_axis) == n_e - 1)
@@ -110,21 +127,25 @@ def spmm_block(rows: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
     grid = (n_blocks, d // bd, e_blk // be)
     kernel = functools.partial(_spmm_coo_kernel, n_e=grid[2], n_rows=dpc,
                                n_src=n_src, edge_axis=2)
+    # edge tiles ride as [n_blocks, 1, e_blk] with the block axis squeezed:
+    # a (1, be) block over [n_blocks, e_blk] breaks the TPU's (8, 128)
+    # tiling rule, a (1, be) block over a unit middle axis does not
+    edge_spec = pl.BlockSpec((None, 1, be), lambda i, j, k: (i, 0, k))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, be), lambda i, j, k: (i, k)),
-            pl.BlockSpec((1, be), lambda i, j, k: (i, k)),
-            pl.BlockSpec((1, be), lambda i, j, k: (i, k)),
+            edge_spec, edge_spec, edge_spec,
             pl.BlockSpec((n_src, bd), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((dpc, bd), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks * dpc, d), x.dtype),
+        out_shape=_out_struct((n_blocks * dpc, d), x.dtype, rows, cols, vals,
+                              x),
         scratch_shapes=[pltpu.VMEM((dpc, bd), jnp.float32)],
         interpret=interpret,
-    )(rows.astype(jnp.int32), cols.astype(jnp.int32),
-      vals.astype(jnp.float32), x)
+    )(rows.reshape(n_blocks, 1, e_blk).astype(jnp.int32),
+      cols.reshape(n_blocks, 1, e_blk).astype(jnp.int32),
+      vals.reshape(n_blocks, 1, e_blk).astype(jnp.float32), x)
 
 
 @functools.partial(jax.jit, static_argnames=("n_dst", "bd", "be", "interpret"))
@@ -154,7 +175,7 @@ def spmm(rows: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
             pl.BlockSpec((n_src, bd), lambda j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((n_dst, bd), lambda j, k: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((n_dst, d), x.dtype),
+        out_shape=_out_struct((n_dst, d), x.dtype, rows, cols, vals, x),
         scratch_shapes=[pltpu.VMEM((n_dst, bd), jnp.float32)],
         interpret=interpret,
     )(rows.reshape(1, e).astype(jnp.int32),
@@ -165,43 +186,62 @@ def spmm(rows: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
 # ---------------------------------------------------------------------------
 # Pre-reduced ELL family (the hot path): src-tiled, scatter-free.
 # ---------------------------------------------------------------------------
-def _spmm_ell_kernel(cols_ref, vals_ref, x_ref, o_ref, acc_ref, *,
-                     n_s: int, bs: int, kc: int = 16):
-    """Gather-accumulate over one ELL row tile × one source tile.
+#: Degree-axis tile of the ELL kernel: a bucket wider than this is walked
+#: in ``ELL_BK``-wide slices along an innermost grid axis (the wrapper pads
+#: K up to a multiple), so a hub bucket never stages its whole [br, K]
+#: table in VMEM (a K = 512 bucket was refused VMEM on a v5e).  128 is the
+#: lane width: a block that is not the whole bucket must be a multiple.
+ELL_BK = 128
+
+
+def _spmm_ell_kernel(cols_ref, vals_ref, x_ref, o_ref, acc_ref, merge_ref, *,
+                     n_s: int, n_k: int, bs: int, kc: int = 16):
+    """Gather-accumulate over one ELL row tile × one source tile × one
+    degree slice.
 
     Builds the merge matrix S[r, s] = Σ_k vals[r,k]·[cols[r,k] == s_global]
-    on the VPU (the Reduced Register File fold), then a single MXU matmul
-    S @ X.  Entries whose column lies outside this source tile never match
-    the tile-local iota — source tiling costs nothing.  Padding entries
-    carry weight 0 AND point at the plan's dedicated zero row, so they are
-    no-ops twice over.
+    on the VPU (the Reduced Register File fold), summed over the degree
+    slices into ``merge_ref``; after the last slice a single MXU matmul
+    S @ X accumulates into ``acc_ref``.  Entries whose column lies outside
+    this source tile never match the tile-local iota — source tiling costs
+    nothing.  Padding entries carry weight 0 AND point at the plan's
+    dedicated zero row, so they are no-ops twice over.
 
-    The degree axis is folded in static chunks of ``kc`` so the one-hot
-    intermediate is [br, ≤kc, bs] — hub buckets (merged degree in the
-    thousands) stay a few hundred KB of VMEM instead of scaling the
-    temporary with K.
+    Within a slice the degree axis is folded in static chunks of ``kc`` so
+    the one-hot intermediate is [br, ≤kc, bs].
     """
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
+    s_idx, k_idx = pl.program_id(2), pl.program_id(3)
+
+    @pl.when((s_idx == 0) & (k_idx == 0))
+    def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    cols = cols_ref[...]                        # [br, K] int32
-    vals = vals_ref[...]                        # [br, K] f32 (0 = padding)
-    x = x_ref[...]                              # [bs, bd] VMEM source tile
-    local = cols - pl.program_id(2) * bs        # tile-local column ids
-    br, K = cols.shape
-    merge = jnp.zeros((br, bs), jnp.float32)    # [br, bs] — scatter-free
-    for k0 in range(0, K, kc):
+    @pl.when(k_idx == 0)
+    def _init_merge():
+        merge_ref[...] = jnp.zeros_like(merge_ref)
+
+    cols = cols_ref[...]                        # [br, bk] int32
+    vals = vals_ref[...]                        # [br, bk] f32 (0 = padding)
+    local = cols - s_idx * bs                   # tile-local column ids
+    br, bk = cols.shape
+    merge = merge_ref[...]                      # [br, bs] — scatter-free
+    for k0 in range(0, bk, kc):
         lc = local[:, k0:k0 + kc]
         lv = vals[:, k0:k0 + kc]
         s_iota = jax.lax.broadcasted_iota(
             jnp.int32, (br, lc.shape[1], bs), 2)
         merge += jnp.where(s_iota == lc[:, :, None], lv[:, :, None],
                            0.0).sum(axis=1)
-    acc_ref[...] += jnp.dot(merge, x.astype(jnp.float32),
-                            preferred_element_type=jnp.float32)
+    merge_ref[...] = merge
 
-    @pl.when(pl.program_id(2) == n_s - 1)
+    @pl.when(k_idx == n_k - 1)
+    def _matmul():
+        acc_ref[...] += jnp.dot(merge_ref[...],
+                                x_ref[...].astype(jnp.float32),
+                                precision=_F32,
+                                preferred_element_type=jnp.float32)
+
+    @pl.when((s_idx == n_s - 1) & (k_idx == n_k - 1))
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
@@ -214,30 +254,36 @@ def spmm_ell(cols: jnp.ndarray, vals: jnp.ndarray, x: jnp.ndarray, *,
 
     ``cols``/``vals``: [nb, K] one degree bucket of an
     :class:`repro.kernels.edgeplan.EllTables`; ``x``: [n_src_p, d] with the
-    plan's dedicated zero row included.  Grid = (nb/br, d/bd, n_src_p/bs)
-    with the SOURCE axis innermost — only a [bs, bd] tile of ``x`` is
-    resident per step, so the kernel scales past whole-shard VMEM staging.
-    All of nb, d, n_src_p must be tile multiples
-    (:func:`repro.kernels.ops.spmm_ell` absorbs padding).
+    plan's dedicated zero row included.  Grid = (nb/br, d/bd, n_src_p/bs,
+    K/bk) with the SOURCE and then the DEGREE axis innermost — only a
+    [bs, bd] tile of ``x`` and a [br, bk] slice of the tables are resident
+    per step (bk = min(K, ``ELL_BK``)), so the kernel scales past
+    whole-shard VMEM staging and past hub buckets of any width.  All of
+    nb, d, n_src_p must be tile multiples and K ≤ ``ELL_BK`` or a multiple
+    of it (:func:`repro.kernels.ops.spmm_ell` absorbs padding).
     """
     nb, K = cols.shape
     n_src_p, d = x.shape
-    if nb % br or d % bd or n_src_p % bs:
-        raise ValueError(f"nb={nb}, d={d}, n_src={n_src_p} not divisible by "
-                         f"(br={br}, bd={bd}, bs={bs})")
-    grid = (nb // br, d // bd, n_src_p // bs)
-    kernel = functools.partial(_spmm_ell_kernel, n_s=grid[2], bs=bs)
+    bk = min(K, ELL_BK)
+    if nb % br or d % bd or n_src_p % bs or K % bk:
+        raise ValueError(f"nb={nb}, d={d}, n_src={n_src_p}, K={K} not "
+                         f"divisible by (br={br}, bd={bd}, bs={bs}, "
+                         f"bk={bk})")
+    grid = (nb // br, d // bd, n_src_p // bs, K // bk)
+    kernel = functools.partial(_spmm_ell_kernel, n_s=grid[2], n_k=grid[3],
+                               bs=bs)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((br, K), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((br, K), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((bs, bd), lambda i, j, k: (k, j)),
+            pl.BlockSpec((br, bk), lambda i, j, k, kk: (i, kk)),
+            pl.BlockSpec((br, bk), lambda i, j, k, kk: (i, kk)),
+            pl.BlockSpec((bs, bd), lambda i, j, k, kk: (k, j)),
         ],
-        out_specs=pl.BlockSpec((br, bd), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((nb, d), x.dtype),
-        scratch_shapes=[pltpu.VMEM((br, bd), jnp.float32)],
+        out_specs=pl.BlockSpec((br, bd), lambda i, j, k, kk: (i, j)),
+        out_shape=_out_struct((nb, d), x.dtype, cols, vals, x),
+        scratch_shapes=[pltpu.VMEM((br, bd), jnp.float32),
+                        pltpu.VMEM((br, bs), jnp.float32)],
         interpret=interpret,
     )(cols.astype(jnp.int32), vals.astype(jnp.float32), x)
 

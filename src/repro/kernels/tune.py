@@ -12,7 +12,7 @@ just reads the file.
 
 Resolution order of :func:`get_config`:
 
-1. in-process cache;
+1. in-process cache (or a config set with :func:`pin`);
 2. the JSON file at ``$REPRO_AUTOTUNE_PATH`` (default
    ``BENCH_autotune.json`` in the CWD — benchmarks/CI write and upload it);
 3. backend defaults (no implicit sweep: tests and library imports must stay
@@ -87,6 +87,14 @@ def get_config() -> Dict:
             pass                      # malformed config block → defaults
     _config = cfg
     return cfg
+
+
+def pin(cfg: Dict) -> None:
+    """Serve ``cfg`` from :func:`get_config` for the rest of this process,
+    reading no record file (a run that must not depend on what an earlier
+    sweep left on disk pins its tiles)."""
+    global _config
+    _config = dict(cfg)
 
 
 def reset() -> None:

@@ -9,23 +9,31 @@ inter-pod links (DCN in a real deployment).
 A FUNCTION, not a module constant: importing this module must never touch
 jax device state (the dry-run sets XLA_FLAGS before first jax init; smoke
 tests and benches see the real 1-CPU backend).
+
+Every mesh here types its axes ``AxisType.Auto``: the code places and
+shards its arrays itself (``NamedSharding`` + ``shard_map``), and JAX
+0.9's default ``Explicit`` axes would put shardings into array types.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+import os
+from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Arbitrary mesh (tests: small meshes on the 16 host devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh with ``Auto`` axes (tests: small meshes on the 16
+    host devices)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_topology_mesh(n_cores: int, topology: str = "hypercube",
@@ -48,10 +56,27 @@ def make_topology_mesh(n_cores: int, topology: str = "hypercube",
             f"need {n_cores} devices for n_cores={n_cores}, have "
             f"{len(jax.devices())} — set XLA_FLAGS="
             "--xla_force_host_platform_device_count")
-    return jax.make_mesh((n_cores,), (axis,))
+    return make_mesh((n_cores,), (axis,))
 
 
-# Hardware constants (TPU v5e-like target, per assignment):
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
+def forced_host_env(n_devices: int, src: str) -> dict:
+    """Environment for a child process that runs on ``n_devices`` forced
+    host CPU devices, with ``src`` on its ``PYTHONPATH``.
+
+    The benchmarks' and planner's re-exec launchers use this.  They time
+    XLA's CPU backend, and a child on a TPU host would wait on the chip
+    that this (parent) process already holds, so on a TPU backend this
+    raises instead of launching anything.
+    """
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "this launcher re-executes itself on forced host CPU devices; "
+            "on a TPU host the parent process holds the chip and a child "
+            "would hang on it or time the CPU under the chip's name — "
+            "run it with JAX_PLATFORMS=cpu, or measure the chip in one "
+            "process (chip_smoke.py)")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices} "
+                        + env.get("XLA_FLAGS", "")).strip()
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    return env

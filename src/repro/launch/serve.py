@@ -25,6 +25,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro.launch import compile_cache
+
 
 def _train_checkpoint(args, ckpt_dir: str):
     """Train a few steps on ``--n-cores`` simulated devices and checkpoint
@@ -121,6 +123,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     # CI host load
     ap.add_argument("--p99-budget-ms", type=float, default=400.0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     from repro.serving import InferenceService, poisson_trace
 

@@ -47,6 +47,7 @@ from repro.distributed.gcn_train import init_params
 from repro.engine import Engine, EngineConfig
 from repro.featurestore import FeatureStore, HotVertexCache, get_store
 from repro.graph import GraphDataset, NeighborSampler, make_dataset
+from repro.launch import compile_cache
 
 
 class Trainer:
@@ -496,6 +497,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                          " rebuild the Trainer, resume, and assert the "
                          "resumed trajectory matches an uninterrupted run")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     def build(pipeline: str, ckpt: Optional[str]) -> Trainer:
         fs = None if args.feature_store == "device" else args.feature_store

@@ -16,6 +16,7 @@ factor 1.25).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -43,7 +44,8 @@ def init_moe_params(key, cfg: ArchConfig, dtype=jnp.bfloat16) -> Params:
 
 def capacity(n_tokens: int, n_experts: int, topk: int,
              factor: float = 1.25) -> int:
-    c = int(factor * n_tokens * topk / n_experts)
+    # ceil: a truncated share can fall short of the load it must cover
+    c = math.ceil(factor * n_tokens * topk / n_experts)
     return max(8, ((c + 7) // 8) * 8)          # pad to 8 for TPU layout
 
 

@@ -89,6 +89,26 @@ def test_spmm_ell_kernel_direct(rng):
     np.testing.assert_allclose(out_t, ref, rtol=1e-5, atol=1e-5)
 
 
+def test_spmm_ell_hub_bucket_degree_slices(rng):
+    """A bucket wider than the kernel's degree tile (K=300 → padded to 384,
+    three grid slices summed into one merge matrix) vs a dense gather."""
+    import jax.numpy as jnp
+    from repro.kernels.ops import spmm_ell
+    from repro.kernels.spmm import ELL_BK
+
+    nb, K, n_src, d = 19, 300, 150, 9
+    assert K > ELL_BK
+    cols = rng.integers(0, n_src + 1, (nb, K)).astype(np.int32)
+    vals = rng.standard_normal((nb, K)).astype(np.float32)
+    vals[cols == n_src] = 0.0
+    x = rng.standard_normal((n_src, d)).astype(np.float32)
+    xz = np.concatenate([x, np.zeros((1, d), np.float32)])
+    ref = (xz[cols] * vals[..., None]).sum(axis=1)
+    out = np.asarray(spmm_ell(jnp.asarray(cols), jnp.asarray(vals),
+                              jnp.asarray(x)))
+    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
+
+
 def test_ell_padding_never_touches_real_rows(rng):
     """Poisoned row 0: padding must gather the dedicated zero row, not real
     data — even when every padding val is (wrongly) nonzero."""

@@ -289,3 +289,41 @@ def test_trainer_every_spec_matches_oracle_on_two_devices():
             assert abs(acc - ref_acc) <= 0.5, (spec, acc, ref_acc)
         print('OK', specs)
     """), n_devices=2)
+
+
+def test_reexec_launchers_refuse_a_tpu_backend(monkeypatch):
+    """A launcher that re-execs itself on forced host devices must not
+    start a child on a TPU host (the parent holds the chip)."""
+    import jax
+    from repro.launch.mesh import forced_host_env
+
+    env = forced_host_env(4, "src")
+    assert "--xla_force_host_platform_device_count=4" in env["XLA_FLAGS"]
+    assert env["PYTHONPATH"].startswith("src")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the chip"):
+        forced_host_env(4, "src")
+
+
+def test_compile_cache_dir_is_fixed_or_the_env_one(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper leaves JAX's own
+    setting alone; without it, the cache goes to the checkout's fixed
+    ``.jax_cache`` — never a temporary or per-process path."""
+    import os
+
+    import jax
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(compile_cache.ENV)
+        path = compile_cache.enable()
+        assert path == compile_cache.DEFAULT_DIR
+        assert jax.config.jax_compilation_cache_dir == path
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
