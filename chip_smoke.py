@@ -8,24 +8,27 @@ plain float32 ``jax.numpy`` reference.
 One chip runs two phases:
 
 (a) **Kernel parity** — ``repro.kernels.ops.ell_aggregate`` forward and
-    gradient through the native Pallas ELL kernel, on a power-law graph
-    whose plan holds degree buckets of K ≥ 512 in both directions, against
-    dense ``A @ x`` and ``Aᵀ g`` at ``precision="highest"``.
+    gradient through the native Pallas ELL kernel (its ``tpu_custom_call``
+    must be in the compiled HLO), on a power-law graph whose plan holds
+    degree buckets of K ≥ 512 in both directions, against dense ``A @ x``
+    and ``Aᵀ g`` at ``precision="highest"``.
 (b) **Training** — ``Trainer("ell+pipelined", "flickr", n_cores=1)`` at the
     paper's §5.1 widths (features 500, hidden 256, 7 classes, batch 1024,
     fanouts ``configs.gcn_paper.FANOUTS``) on the full synthetic Flickr
-    graph, for ``--steps`` steps.  The first step's loss must match a plain
-    float32 segment-sum GCN forward on the same first batch and every loss
+    graph, for ``STEPS`` steps.  Against a plain float32 segment-sum GCN
+    on the same first batch and initial params: the logits, the first loss
+    and the first SGD update (so the backward too) must match; every loss
     must be finite.
 
-``--chips 4`` runs only the ``n_cores=4`` hypercube training steps and
-compares them, step by step, with the same batch stream at ``n_cores=1``
-and the first loss with the reference.
+``--chips 4`` runs only the training phase at ``n_cores=4`` (hypercube)
+and at ``n_cores=1`` on the same batch stream: each against the
+reference as above, and the 4-core losses and parameters after ``STEPS``
+steps against the 1-core ones.
 
 The spec is pinned (``"auto"`` is never resolved) and the kernel tiles come
 from ``repro.kernels.tune.DEFAULTS["tpu"]``: no record file is read.  The
 script exits nonzero, printing no result, when JAX finds no TPU or any
-phase fails.  Its last stdout line is
+check fails.  Its last stdout line is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
 """
 from __future__ import annotations
@@ -40,16 +43,41 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(HERE, "src"))
 
 SPEC = "ell+pipelined"
-#: |first-step loss − reference|: TPU's default f32 matmul precision in the
-#: trainer's combination step vs the "highest"-precision reference.
-LOSS_TOL = 1e-3
-#: max |kernel − dense| over max |dense|, forward and gradient.
-KERNEL_RTOL = 1e-4
+SEED = 0
+STEPS = 5
+WARM_REPS = 3             # timed re-runs of step 0's compiled program
+DATASET_SCALE = 1.0       # full synthetic Flickr
 PAD_MULTIPLE = 1024       # coarse sampler padding: layer dims repeat
+# kernel parity graph: power-law, hub buckets of K >= MIN_HUB_K both ways
+KERNEL_N, KERNEL_AVG_DEG, KERNEL_D, MIN_HUB_K = 4096, 16, 256, 512
+
+# Tolerances.  Every matmul runs at full f32 (``kernels.spmm.F32_MATMUL``),
+# so the engine and the reference differ only in summation order.  Sound
+# one-chip readings (TPU v5e): kernel rel. error 3.6e-7 / 5.6e-7, logits
+# 1.7e-7, first loss |diff| 0 to 9.5e-7, first update 2.3e-7.  Faults read:
+# a bf16-pass kernel 2.5e-3 on the kernel check; a bf16-pass combination
+# matmul 3.9e-3 on the logits and 6.1e-2 on the update (loss |diff| 9.5e-7:
+# the loss alone cannot see it); a zeroed aggregation 1.0.
+#: max |kernel − dense| / max |dense|, forward and gradient.
+KERNEL_RTOL = 1e-5
+#: max |logits − reference| / max |reference| on the first batch.
+LOGIT_RTOL = 1e-5
+#: |loss − reference| on the first batch; 4-core vs 1-core losses per step.
+LOSS_TOL = 1e-5
+#: per layer, max |update − reference update| / max |reference update|
+#: (looser: the backward adds reductions, and no 4-core reading preceded it),
+#: where an update is ``params0 − params_after``, beyond the float32
+#: rounding of the params (``update_rel``): the first SGD step vs
+#: ``lr · ∇loss`` of the reference, and 4-core vs 1-core after STEPS steps.
+UPDATE_RTOL = 1e-4
 
 
 def log(msg: str) -> None:
     print(msg, flush=True)
+
+
+def verdict(good: bool) -> str:
+    return "ok" if good else "FAIL"
 
 
 class CompileCounter:
@@ -97,9 +125,7 @@ def powerlaw_coo(n: int, avg_deg: int, seed: int):
     return from_edges(rows, cols, vals, n, n)
 
 
-def kernel_parity(n: int = 4096, avg_deg: int = 16, d: int = 256,
-                  seed: int = 0, min_hub_k: int = 512,
-                  require_native: bool = True) -> bool:
+def kernel_parity() -> bool:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -107,28 +133,28 @@ def kernel_parity(n: int = 4096, avg_deg: int = 16, d: int = 256,
     from repro.kernels import edgeplan
     from repro.kernels.ops import ell_aggregate
 
-    coo = powerlaw_coo(n, avg_deg, seed)
+    n, d = KERNEL_N, KERNEL_D
+    coo = powerlaw_coo(n, KERNEL_AVG_DEG, SEED)
     tables = edgeplan.build_plan(coo, caps="pow2").device_tables()
     k_fwd = max(c.shape[1] for c in tables["cols"])
     k_bwd = max(c.shape[1] for c in tables["t_cols"])
     log(f"kernel: graph n={n} nnz={coo.nnz} d={d}  widest bucket "
         f"fwd K={k_fwd} bwd K={k_bwd}")
-    if min(k_fwd, k_bwd) < min_hub_k:
-        log(f"kernel: FAIL no bucket of K >= {min_hub_k} in both directions")
+    if min(k_fwd, k_bwd) < MIN_HUB_K:
+        log(f"kernel: FAIL no bucket of K >= {MIN_HUB_K} in both directions")
         return False
-    rng = np.random.default_rng(seed + 1)
+    rng = np.random.default_rng(SEED + 1)
     x = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
     g = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
 
     fwd = jax.jit(lambda xx: ell_aggregate(tables, xx))
     grad = jax.jit(lambda xx: jax.grad(
         lambda v: jnp.vdot(ell_aggregate(tables, v), g))(xx))
-    if require_native:
-        for name, f in (("fwd", fwd), ("grad", grad)):
-            hlo = f.lower(x).compile().as_text()
-            if "tpu_custom_call" not in hlo:
-                log(f"kernel: FAIL {name} has no Pallas kernel in its HLO")
-                return False
+    for name, f in (("fwd", fwd), ("grad", grad)):
+        hlo = f.lower(x).compile().as_text()
+        if "tpu_custom_call" not in hlo:
+            log(f"kernel: FAIL {name} has no Pallas kernel in its HLO")
+            return False
     y, dx = fwd(x), grad(x)
 
     a = np.zeros((n, n), np.float32)
@@ -146,74 +172,111 @@ def kernel_parity(n: int = 4096, avg_deg: int = 16, d: int = 256,
         good = rel <= KERNEL_RTOL
         ok &= good
         log(f"kernel: {name:10s} max|err|={err:.3e} max|ref|={scale:.3e} "
-            f"rel={rel:.3e} (tol {KERNEL_RTOL:g}) "
-            f"{'ok' if good else 'FAIL'}")
+            f"rel={rel:.3e} (tol {KERNEL_RTOL:g}) {verdict(good)}")
     return ok
 
 
 # ---------------------------------------------------------------------------
 # (b) training
 # ---------------------------------------------------------------------------
-def reference_loss(params, layers, feats, labels) -> float:
-    """Plain float32 GCN forward + mean cross entropy: per hop, deepest
-    first, ``h = segment_sum(vals · (h @ W)[cols], rows)``, ReLU between
-    hops — the engine's CoAg order, written without any of its layouts."""
+def reference_logits(params, layers, feats):
+    """Plain float32 GCN forward: per hop, deepest first,
+    ``h = segment_sum(vals · (h @ W)[cols], rows)``, ReLU between hops —
+    the engine's CoAg order, written without any of its layouts."""
     import jax
     import jax.numpy as jnp
 
     h = jnp.asarray(feats, jnp.float32)
     n_layers = len(layers)
-    with jax.default_matmul_precision("highest"):
-        for l in range(n_layers - 1, -1, -1):
-            coo = layers[l]
-            h = h @ params[n_layers - 1 - l]["w"]
-            msg = jnp.asarray(coo.vals)[:, None] * h[jnp.asarray(coo.cols)]
-            h = jax.ops.segment_sum(msg, jnp.asarray(coo.rows),
-                                    num_segments=coo.n_dst)
-            if l:
-                h = jnp.maximum(h, 0.0)
-    logp = jax.nn.log_softmax(h, -1)
-    nll = -jnp.take_along_axis(logp, jnp.asarray(labels)[:, None], -1)
-    return float(nll.mean())
+    for l in range(n_layers - 1, -1, -1):
+        coo = layers[l]
+        h = jnp.dot(h, params[n_layers - 1 - l]["w"],
+                    precision=jax.lax.Precision.HIGHEST)
+        msg = jnp.asarray(coo.vals)[:, None] * h[jnp.asarray(coo.cols)]
+        h = jax.ops.segment_sum(msg, jnp.asarray(coo.rows),
+                                num_segments=coo.n_dst)
+        if l:
+            h = jnp.maximum(h, 0.0)
+    return h
 
 
-def first_batch(dataset, sampler, batch_size: int, seed: int):
+def reference(params, layers, feats, labels):
+    """(logits, mean cross-entropy loss, ∇loss wrt params) on one batch."""
+    import jax
+    import jax.numpy as jnp
+
+    def loss_fn(p):
+        logits = reference_logits(p, layers, feats)
+        logp = jax.nn.log_softmax(logits, -1)
+        nll = -jnp.take_along_axis(logp, jnp.asarray(labels)[:, None], -1)
+        return nll.mean(), logits
+
+    (loss, logits), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+    return logits, float(loss), grads
+
+
+def update_rel(params_after, params0, want_update, roundings: int) -> float:
+    """Largest, over layers, of max |(params0 − params_after) − want| /
+    max |want|, in float64 on the host (the subtraction is exact).  Each
+    SGD step rounds the params to float32 once, off by at most half an ulp
+    of the params, which can be a sizeable part of a small update; so each
+    element may miss by ``roundings`` half-ulps before the excess counts."""
+    import numpy as np
+
+    worst = 0.0
+    for pa, p0, u in zip(params_after, params0, want_update):
+        pa, p0 = np.asarray(pa["w"]), np.asarray(p0["w"])
+        got = p0.astype(np.float64) - pa.astype(np.float64)
+        want = np.asarray(u["w"], np.float64)
+        half_ulp = 0.5 * np.maximum(np.spacing(np.abs(p0)),
+                                    np.spacing(np.abs(pa))).astype(np.float64)
+        excess = np.maximum(np.abs(got - want) - roundings * half_ulp, 0.0)
+        worst = max(worst, float(np.max(excess) / np.max(np.abs(want))))
+    return worst
+
+
+def first_batch(dataset, sampler):
     """The trainer's first batch, drawn again: the stream is keyed by
     (seed, epoch, batch index) alone."""
+    from repro.configs.gcn_paper import BATCH
     from repro.data import GraphBatchPipeline
 
-    return next(GraphBatchPipeline(dataset, sampler, batch_size, seed=seed))
+    return next(GraphBatchPipeline(dataset, sampler, BATCH, seed=SEED))
 
 
-def train(dataset, *, n_cores: int, steps: int, hidden: int,
-          batch_size: int, fanouts, pad_multiple: int, seed: int,
-          counter: CompileCounter, warm_reps: int = 3) -> dict:
-    """``steps`` Trainer steps (losses, seconds and compiles per step), then
-    ``warm_reps`` timed re-runs of step 0's compiled program on the first
-    batch from the initial params — every training step compiles its own
-    program, so these are the only warm step times."""
+def train(dataset, n_cores: int, counter: CompileCounter) -> dict:
+    """``STEPS`` Trainer steps (losses, params, seconds and compiles per
+    step), then, on the first batch and the initial params, the engine's
+    logits and ``WARM_REPS`` timed re-runs of step 0's compiled program —
+    every training step compiles its own program, so these are the only
+    warm step times."""
     import jax
+    import numpy as np
 
+    from repro.configs.gcn_paper import BATCH, FANOUTS, HIDDEN
     from repro.launch.trainer import Trainer
 
-    tr = Trainer(SPEC, dataset, n_cores=n_cores, hidden=hidden,
-                 batch_size=batch_size, fanouts=fanouts,
-                 pad_multiple=pad_multiple, seed=seed)
-    out = {"params0": tr.params, "losses": [], "secs": [], "compiles": []}
+    tr = Trainer(SPEC, dataset, n_cores=n_cores, hidden=HIDDEN,
+                 batch_size=BATCH, fanouts=FANOUTS,
+                 pad_multiple=PAD_MULTIPLE, seed=SEED)
+    out = {"params0": tr.params, "lr": tr.engine.config.lr, "losses": [],
+           "params": [], "secs": [], "compiles": []}
     try:
-        for _ in range(steps):
+        for _ in range(STEPS):
             c0 = counter.snapshot()[0]
             t0 = time.perf_counter()
             out["losses"] += tr.train_steps(1)   # float(loss): waits
             out["secs"].append(time.perf_counter() - t0)
             out["compiles"].append(counter.snapshot()[0] - c0)
+            out["params"].append(tr.params)
     finally:
         tr.close()
-    out["batch0"] = first_batch(dataset, tr.sampler, batch_size, seed)
+    out["batch0"] = first_batch(dataset, tr.sampler)
     batch = tr.bundle.shard_batch(*out["batch0"])
+    out["logits0"] = np.asarray(tr.bundle.forward(out["params0"], batch))
     c0 = counter.snapshot()[0]
     out["warm"] = []
-    for _ in range(warm_reps):
+    for _ in range(WARM_REPS):
         t0 = time.perf_counter()
         _, loss = jax.block_until_ready(
             tr.bundle.train_step(out["params0"], batch))
@@ -231,30 +294,80 @@ def report_steps(tag: str, run: dict) -> None:
         f" (compiles {run['warm_compiles']}, loss {run['warm_loss']!r})")
 
 
-def training(*, n_cores_list, steps: int, seed: int, counter: CompileCounter,
-             scale: float = 1.0, feat_dim=None, hidden=None, batch_size=None,
-             fanouts=None, pad_multiple: int = PAD_MULTIPLE) -> bool:
+def check_vs_reference(tag: str, run: dict) -> bool:
+    """The run's first batch against the plain reference: logits, loss,
+    and the first SGD update; and every loss finite."""
+    import jax
     import numpy as np
 
+    mb, feats, labels = run["batch0"]
+    logits, loss, grads = reference(run["params0"], mb.layers, feats, labels)
+    logits = np.asarray(logits)
+    ok = True
+
+    rel = float(np.max(np.abs(run["logits0"] - logits))
+                / np.max(np.abs(logits)))
+    good = rel <= LOGIT_RTOL
+    ok &= good
+    log(f"{tag}: first-batch logits {run['logits0'].shape} rel err vs "
+        f"reference {rel:.3e} (tol {LOGIT_RTOL:g}) {verdict(good)}")
+
+    d = abs(run["losses"][0] - loss)
+    good = d <= LOSS_TOL
+    ok &= good
+    log(f"{tag}: first loss {run['losses'][0]!r} reference {loss!r} "
+        f"|diff|={d:.3e} (tol {LOSS_TOL:g}) {verdict(good)}")
+
+    want = jax.tree_util.tree_map(lambda g: run["lr"] * np.asarray(g), grads)
+    rel = update_rel(run["params"][0], run["params0"], want, roundings=1)
+    good = rel <= UPDATE_RTOL
+    ok &= good
+    log(f"{tag}: first update vs reference lr*grad: worst layer rel err "
+        f"{rel:.3e} (tol {UPDATE_RTOL:g}) {verdict(good)}")
+
+    if not np.all(np.isfinite(run["losses"])):
+        ok = False
+        log(f"{tag}: FAIL non-finite loss {run['losses']}")
+    return ok
+
+
+def check_vs_one_core(tag: str, run: dict, base: dict) -> bool:
+    """A multi-core run against the 1-core run on the same batch stream:
+    per-step losses, and the parameters after the last step."""
+    import numpy as np
+
+    d = float(np.max(np.abs(np.asarray(run["losses"])
+                            - np.asarray(base["losses"]))))
+    good_loss = d <= LOSS_TOL
+    log(f"{tag} vs n_cores=1: max |loss diff| over {STEPS} steps {d:.3e} "
+        f"(tol {LOSS_TOL:g}) {verdict(good_loss)}")
+    want = [{"w": np.asarray(p0["w"], np.float64)
+             - np.asarray(p1["w"], np.float64)}
+            for p0, p1 in zip(base["params0"], base["params"][-1])]
+    rel = update_rel(run["params"][-1], run["params0"], want,
+                     roundings=2 * STEPS)
+    good_params = rel <= UPDATE_RTOL
+    log(f"{tag} vs n_cores=1: params after {STEPS} steps, worst layer rel "
+        f"err of the update {rel:.3e} (tol {UPDATE_RTOL:g}) "
+        f"{verdict(good_params)}")
+    return good_loss and good_params
+
+
+def training(n_cores_list, counter: CompileCounter) -> bool:
     from repro.configs.gcn_paper import BATCH, FANOUTS, HIDDEN
     from repro.graph import make_dataset
 
-    hidden = hidden or HIDDEN
-    batch_size = batch_size or BATCH
-    fanouts = fanouts or FANOUTS
     t0 = time.perf_counter()
-    ds = make_dataset("flickr", scale=scale, feat_dim=feat_dim, seed=seed)
+    ds = make_dataset("flickr", scale=DATASET_SCALE, seed=SEED)
     log(f"train: flickr nodes={ds.graph.n_nodes} "
         f"csr_entries={ds.graph.n_edges} feat={ds.features.shape[1]} "
-        f"classes={ds.stats.n_classes} hidden={hidden} batch={batch_size} "
-        f"fanouts={tuple(fanouts)} spec={SPEC} "
+        f"classes={ds.stats.n_classes} hidden={HIDDEN} batch={BATCH} "
+        f"fanouts={tuple(FANOUTS)} spec={SPEC} "
         f"({time.perf_counter() - t0:.1f}s to build)")
     runs = {}
     for p in n_cores_list:
         n0, s0, h0 = counter.snapshot()
-        run = train(ds, n_cores=p, steps=steps, hidden=hidden,
-                    batch_size=batch_size, fanouts=fanouts,
-                    pad_multiple=pad_multiple, seed=seed, counter=counter)
+        run = train(ds, p, counter)
         n1, s1, h1 = counter.snapshot()
         tag = f"train[n_cores={p}]"
         report_steps(tag, run)
@@ -263,39 +376,17 @@ def training(*, n_cores_list, steps: int, seed: int, counter: CompileCounter,
         runs[p] = run
 
     ok = True
-    run = runs[n_cores_list[0]]
-    mb, feats, labels = run["batch0"]
-    ref = reference_loss(run["params0"], mb.layers, feats, labels)
     for p, run in runs.items():
-        losses = run["losses"]
-        d = abs(losses[0] - ref)
-        good = d <= LOSS_TOL
-        ok &= good
-        log(f"train[n_cores={p}]: first loss {losses[0]!r} reference "
-            f"{ref!r} |diff|={d:.3e} (tol {LOSS_TOL:g}) "
-            f"{'ok' if good else 'FAIL'}")
-        if not np.all(np.isfinite(losses)):
-            ok = False
-            log(f"train[n_cores={p}]: FAIL non-finite loss {losses}")
-    if len(runs) > 1:
-        base = np.asarray(runs[1]["losses"])
-        for p, run in runs.items():
-            if p == 1:
-                continue
-            d = float(np.max(np.abs(np.asarray(run["losses"]) - base)))
-            good = d <= LOSS_TOL
-            ok &= good
-            log(f"train[n_cores={p}] vs n_cores=1: max |loss diff| over "
-                f"{steps} steps {d:.3e} (tol {LOSS_TOL:g}) "
-                f"{'ok' if good else 'FAIL'}")
+        ok &= check_vs_reference(f"train[n_cores={p}]", run)
+    for p, run in runs.items():
+        if p != 1:
+            ok &= check_vs_one_core(f"train[n_cores={p}]", run, runs[1])
     return ok
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--steps", type=int, default=5)
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     from repro.launch import compile_cache
@@ -324,16 +415,14 @@ def main(argv=None) -> int:
     results = {}
     if args.chips == 1:
         t0 = time.perf_counter()
-        results["kernel"] = kernel_parity(seed=args.seed)
+        results["kernel"] = kernel_parity()
         log(f"kernel: phase {time.perf_counter() - t0:.1f}s")
         t0 = time.perf_counter()
-        results["train"] = training(n_cores_list=(1,), steps=args.steps,
-                                    seed=args.seed, counter=counter)
+        results["train"] = training((1,), counter)
         log(f"train: phase {time.perf_counter() - t0:.1f}s")
     else:
         t0 = time.perf_counter()
-        results["train4"] = training(n_cores_list=(4, 1), steps=args.steps,
-                                     seed=args.seed, counter=counter)
+        results["train4"] = training((4, 1), counter)
         log(f"train4: phase {time.perf_counter() - t0:.1f}s")
 
     stats = devs[0].memory_stats() or {}

@@ -66,7 +66,9 @@ class EngineConfig:
               vertices); a field, not a spec part
     axis:     mesh axis name that plays the paper's 16-core hypercube
     lr:       SGD learning rate baked into ``train_step``
-    precision: accumulation precision (``"fp32"`` only today)
+    precision: accumulation precision (``"fp32"`` only today: every matmul
+              of the step, combination and kernels, at
+              ``repro.kernels.spmm.F32_MATMUL``)
     """
 
     format: str = "coo"

@@ -37,6 +37,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.compat import shard_map
+from repro.kernels.spmm import F32_MATMUL
 
 from . import formats as _formats  # noqa: F401  (registers built-ins)
 from .config import EngineConfig
@@ -363,7 +364,8 @@ class EngineBundle:
         n_layers = len(params)
         for l in range(n_layers - 1, -1, -1):
             n_dst, _ = dims[l]
-            h = h @ params[n_layers - 1 - l]["w"]      # local combination
+            h = jnp.dot(h, params[n_layers - 1 - l]["w"],  # combination
+                        precision=F32_MATMUL)
             h = self.format.device_aggregate(
                 self.config.schedule, self.axis, self.ndim, n_dst,
                 edges[l], h, self.n_chunks, topology=self.config.topology)

@@ -48,11 +48,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-#: Every kernel matmul runs at full f32 precision: Mosaic's default for f32
-#: operands is one bf16 pass, which put the ELL kernel 2.5e-3 (relative)
-#: off a float32 reference on a TPU v5e — outside the engine's fp32
-#: contract (``EngineConfig.precision``).
-_F32 = jax.lax.Precision.HIGHEST
+#: The engine's fp32 contract (``EngineConfig.precision``): every matmul of a
+#: step — these kernels' and the engine's combination ``h @ W`` — runs at
+#: full f32 precision.  The TPU default for f32 operands is one bf16 pass,
+#: which put the ELL kernel 2.5e-3 (relative) off a float32 reference on a
+#: TPU v5e.
+F32_MATMUL = jax.lax.Precision.HIGHEST
 
 
 def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
@@ -90,14 +91,14 @@ def _spmm_coo_kernel(rows_ref, cols_ref, vals_ref, x_ref, o_ref, acc_ref, *,
     # and gather nothing at all.
     src_iota = jax.lax.broadcasted_iota(jnp.int32, (be, n_src), 1)
     onehot_src = (src_iota == cols[:, None]).astype(x.dtype)
-    g = jnp.dot(onehot_src, x, precision=_F32,
+    g = jnp.dot(onehot_src, x, precision=F32_MATMUL,
                 preferred_element_type=jnp.float32)
 
     # scatter-add via one-hot matmul, edge weights folded into the one-hot
     dst_iota = jax.lax.broadcasted_iota(jnp.int32, (n_rows, be), 0)
     onehot_dst = jnp.where(dst_iota == rows[None, :], vals[None, :], 0.0)
     acc_ref[...] += jnp.dot(onehot_dst.astype(jnp.float32), g,
-                            precision=_F32,
+                            precision=F32_MATMUL,
                             preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(edge_axis) == n_e - 1)
@@ -238,7 +239,7 @@ def _spmm_ell_kernel(cols_ref, vals_ref, x_ref, o_ref, acc_ref, merge_ref, *,
     def _matmul():
         acc_ref[...] += jnp.dot(merge_ref[...],
                                 x_ref[...].astype(jnp.float32),
-                                precision=_F32,
+                                precision=F32_MATMUL,
                                 preferred_element_type=jnp.float32)
 
     @pl.when((s_idx == n_s - 1) & (k_idx == n_k - 1))
