@@ -552,7 +552,8 @@ def measured_redundancy(n_cores: int = 4, batch: int = 256, mid: int = 1024,
         _, loss = step(params, b)     # compile; first-step loss for the
         jax.block_until_ready(loss)   # bit-match gate (same params0)
         runs[name] = {"step": step, "batch": b, "params": params,
-                      "loss": float(loss), "report": b["report"],
+                      "loss": float(loss),
+                      "report": bundle.plan_report(_MB(), feat),
                       "times": []}
     for _ in range(n_trials):
         for arm in runs.values():

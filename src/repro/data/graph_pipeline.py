@@ -26,6 +26,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.graph.datasets import GraphDataset
@@ -33,9 +34,11 @@ from repro.graph.sampler import MiniBatch, NeighborSampler
 
 
 def sample_batch(dataset: GraphDataset, sampler: NeighborSampler,
-                 seeds: np.ndarray, nnz_pad, rng: np.random.Generator
-                 ) -> Tuple[MiniBatch, np.ndarray]:
-    """The feature-free half of batch assembly: ``(mb, labels)``.
+                 seeds: np.ndarray, nnz_pad, rng: np.random.Generator,
+                 batch: int = -1) -> Tuple[MiniBatch, np.ndarray]:
+    """The feature-free half of batch assembly: ``(mb, labels)``, under the
+    profiler span ``pipeline.sample``; ``batch`` (the pipeline's batch
+    index) tags the span and rides on ``mb.batch``.
 
     Labels are row-fancy-indexed (single-label ``[n]`` ints and multilabel
     ``[n, c]`` rows alike) with padded seed rows zero-padded — they index
@@ -43,24 +46,28 @@ def sample_batch(dataset: GraphDataset, sampler: NeighborSampler,
     counts only real rows when masked; val accuracy scores only the first
     ``len(seeds)`` rows).  The staged store pipeline runs this stage alone
     and gathers features in its own stage."""
-    mb = sampler.sample(seeds, nnz_pad=nnz_pad, rng=rng)
-    pad = mb.layers[0].n_dst - len(seeds)
-    labels = dataset.labels[np.pad(seeds, (0, pad))]
-    return mb, labels
+    with jax.profiler.TraceAnnotation("pipeline.sample", batch=batch):
+        mb = sampler.sample(seeds, nnz_pad=nnz_pad, rng=rng)
+        pad = mb.layers[0].n_dst - len(seeds)
+        labels = dataset.labels[np.pad(seeds, (0, pad))]
+    return dataclasses.replace(mb, batch=batch), labels
 
 
 def gather_features(features, input_nodes: np.ndarray,
-                    n_nodes: int) -> np.ndarray:
+                    n_nodes: int, batch: int = -1) -> np.ndarray:
     """THE frontier-gather rule: clamp-index padded frontier slots to the
     last real node, then fancy-index ``features`` — a dense ndarray, a
     :class:`~repro.featurestore.FeatureStore`, or a
     :class:`~repro.featurestore.HotVertexCache` alike (all three share
-    the row-fancy-indexing surface, so one rule serves every tier)."""
-    return features[np.minimum(input_nodes, n_nodes - 1)]
+    the row-fancy-indexing surface, so one rule serves every tier).
+    Runs under the profiler span ``pipeline.gather``, tagged ``batch``."""
+    with jax.profiler.TraceAnnotation("pipeline.gather", batch=batch):
+        return features[np.minimum(input_nodes, n_nodes - 1)]
 
 
 def assemble_batch(dataset: GraphDataset, sampler: NeighborSampler,
-                   seeds: np.ndarray, nnz_pad, rng: np.random.Generator
+                   seeds: np.ndarray, nnz_pad, rng: np.random.Generator,
+                   batch: int = -1
                    ) -> Tuple[MiniBatch, np.ndarray, np.ndarray]:
     """One sampled batch: ``(mb, features, labels)`` for ``seeds``.
 
@@ -68,9 +75,9 @@ def assemble_batch(dataset: GraphDataset, sampler: NeighborSampler,
     Trainer's validation path so padding/label semantics can never
     diverge: :func:`sample_batch` + :func:`gather_features` fused —
     the staged pipeline calls the two halves as separate stages."""
-    mb, labels = sample_batch(dataset, sampler, seeds, nnz_pad, rng)
+    mb, labels = sample_batch(dataset, sampler, seeds, nnz_pad, rng, batch)
     feats = gather_features(dataset.features, mb.input_nodes,
-                            dataset.graph.n_nodes)
+                            dataset.graph.n_nodes, batch)
     return mb, feats, labels
 
 
@@ -116,13 +123,14 @@ class GraphBatchPipeline:
         # per-batch generator keyed by (seed, epoch, batch): resume-exact
         rng = np.random.default_rng(
             np.random.SeedSequence([self.seed, self.epoch, self.batch_idx]))
+        batch = self.batch_idx
         self.batch_idx += 1
         nnz_pad = self.sampler.static_nnz(self.batch_size)
         if self.defer_gather:
             return sample_batch(self.dataset, self.sampler, seeds,
-                                nnz_pad, rng)
+                                nnz_pad, rng, batch)
         return assemble_batch(self.dataset, self.sampler, seeds,
-                              nnz_pad, rng)
+                              nnz_pad, rng, batch)
 
     def state(self) -> Dict[str, int]:
         return {"seed": self.seed, "epoch": self.epoch,

@@ -570,11 +570,13 @@ def _ell_bwd(axis_name, ndim, n_dst, n_chunks, topology, res, ct):
     from repro.kernels.ops import ell_apply
 
     tables = res
-    # mirror schedule, same topology, same waves: all-gather the error rows
-    e_full = _topo(topology).allgather_pipelined(ct, axis_name, 1 << ndim,
-                                                 n_chunks)
-    # then the column-major ELL walk of the SAME plan — scatter-free Aᵀ
-    dx_local = ell_apply(tables, e_full.reshape(n_dst, -1), transpose=True)
+    with jax.named_scope("mirror_backward"):
+        # mirror schedule, same topology, same waves: all-gather the error rows
+        e_full = _topo(topology).allgather_pipelined(ct, axis_name,
+                                                     1 << ndim, n_chunks)
+        # then the column-major ELL walk of the SAME plan — scatter-free Aᵀ
+        dx_local = ell_apply(tables, e_full.reshape(n_dst, -1),
+                             transpose=True)
     return (zero_ct(tables), dx_local)
 
 

@@ -221,40 +221,51 @@ class EngineBundle:
         :class:`~repro.featurestore.HotVertexCache` — in which case the
         frontier gather (``mb.input_nodes``, clamp-indexed like
         :func:`repro.data.gather_features`) happens HERE, store-side, so
-        any shard_batch caller trains out-of-core with no other change."""
-        if hasattr(features, "gather"):   # FeatureStore / HotVertexCache
-            ids = np.minimum(np.asarray(mb.input_nodes, np.int64),
-                             features.shape[0] - 1)
-            features = features.gather(ids)
-        features = np.asarray(features, np.float32)
-        mb, features = self._apply_partition(mb, features)
-        edges, dims = self.format.prepare_batch(mb, self.n_cores,
-                                                self.config)
-        labels = np.asarray(labels)
-        if labels.ndim == 2:
-            # multilabel rows → the dominant class, the single-label proxy
-            # every engine train_step shares (BCE is a loss-layer variant,
-            # not an aggregation-format concern)
-            labels = labels.argmax(-1)
-        return {
-            "edges": edges,
-            "dims": dims,
-            "x": features,
-            "labels": labels.astype(np.int32),
-            "report": self._plan_report(mb, features.shape[-1]),
-        }
+        any shard_batch caller trains out-of-core with no other change.
 
-    def _apply_partition(self, mb, features: np.ndarray):
+        Runs under the profiler span ``engine.layout``, tagged with the
+        pipeline's batch index (``mb.batch``, -1 where ``mb`` has none),
+        which the returned batch carries on to :meth:`commit_batch`."""
+        batch = getattr(mb, "batch", -1)
+        with jax.profiler.TraceAnnotation("engine.layout", batch=batch):
+            if hasattr(features, "gather"):  # FeatureStore / HotVertexCache
+                ids = np.minimum(np.asarray(mb.input_nodes, np.int64),
+                                 features.shape[0] - 1)
+                features = features.gather(ids)
+            features = np.asarray(features, np.float32)
+            mb, perm = self._apply_partition(mb)
+            if perm is not None:
+                # frontier rows move with their space-L ids: new row
+                # perm[v] = old v
+                features = features[np.argsort(perm, kind="stable")]
+            edges, dims = self.format.prepare_batch(mb, self.n_cores,
+                                                    self.config)
+            labels = np.asarray(labels)
+            if labels.ndim == 2:
+                # multilabel rows → the dominant class, the single-label
+                # proxy every engine train_step shares (BCE is a loss-layer
+                # variant, not an aggregation-format concern)
+                labels = labels.argmax(-1)
+            return {
+                "edges": edges,
+                "dims": dims,
+                "x": features,
+                "labels": labels.astype(np.int32),
+                "batch": batch,
+            }
+
+    def _apply_partition(self, mb):
         """``partition="mincom"``: relabel every non-batch node space with
         the communication-minimizing permutation chain
         (:func:`repro.graph.partition.mincom_layer_perms` — space 0 stays
         identity, so labels, logits and checkpointed batch order never
-        move) and permute the frontier feature rows to match.  Cached on
-        the layer chain's identity in the shared edge-plan LRU — repeated
-        batches (and the aggregator path) pay the greedy passes once.
-        ``naive`` (and a single-core mesh) returns the batch untouched."""
+        move); returns the relabeled batch and the deepest space's
+        permutation, which the frontier feature rows must follow.  Cached
+        on the layer chain's identity in the shared edge-plan LRU —
+        repeated batches (and the aggregator path) pay the greedy passes
+        once.  ``naive`` (and a single-core mesh) returns ``(mb, None)``."""
         if self.config.partition != "mincom" or self.n_cores <= 1:
-            return mb, features
+            return mb, None
         from repro.graph.coo import from_edges
         from repro.graph.partition import mincom_layer_perms
         from repro.kernels import edgeplan
@@ -276,19 +287,21 @@ class EngineBundle:
         class _RelabeledMB:           # duck-typed: formats read .layers only
             layers = relabeled
 
-        # frontier rows move with their space-L ids: new row perm[v] = old v
-        x = features[np.argsort(perms[-1], kind="stable")]
-        return _RelabeledMB(), x
+        return _RelabeledMB(), perms[-1]
 
-    def _plan_report(self, mb, d: int) -> Dict[str, float]:
-        """Host-side partition/merge observability for one prepared batch:
-        measured exchange ``wire_bytes`` (per-core, summed over hop layers,
-        post-merge row accounting through ``Topology.plan(wire_rows=...)``)
-        plus the redundancy tier's ``virtual_vertices``/``pair_coverage``
-        (ELL format only; the shard build is LRU-cached, so reading the
-        stats here costs a cache hit)."""
+    def plan_report(self, mb, d: int) -> Dict[str, float]:
+        """Partition/merge accounting for one sampled batch at feature
+        width ``d``, under this bundle's partition: measured exchange
+        ``wire_bytes`` (per-core, summed over hop layers, post-merge row
+        accounting through ``Topology.plan(wire_rows=...)``) plus the
+        redundancy tier's ``virtual_vertices``/``pair_coverage``/
+        ``flop_reduction`` (ELL format only; the partition and the shard
+        build are LRU-cached, so after :meth:`prepare_batch` of the same
+        batch this costs cache hits and the wire-row count).  Host work
+        that no training step needs: callers ask for it."""
         from repro.graph.partition import exchange_rows
 
+        mb, _ = self._apply_partition(mb)
         wire_bytes = 0
         for coo in mb.layers:
             wr = exchange_rows(np.asarray(coo.rows), np.asarray(coo.cols),
@@ -322,7 +335,12 @@ class EngineBundle:
         :class:`~jax.sharding.NamedSharding` when the bundle has a mesh —
         placement happens once per minibatch, never per step (uncommitted
         arrays get re-laid-out by jit on EVERY step, the measured cause of
-        a past ``agg_fwd_speedup < 1`` regression)."""
+        a past ``agg_fwd_speedup < 1`` regression).
+
+        Runs under the profiler span ``engine.place``, tagged with the
+        batch index and ``bytes``, the summed size of the placed leaves
+        (the span times the host call; the transfer completes
+        asynchronously)."""
         if self.mesh is not None:
             from repro.distributed.sharding import leading_axis_put
 
@@ -330,19 +348,14 @@ class EngineBundle:
                 return leading_axis_put(self.mesh, a, self.axis)
         else:
             put = jnp.asarray
-        out = {
-            "edges": [jax.tree_util.tree_map(put, leaves)
-                      for leaves in host_batch["edges"]],
-            "dims": host_batch["dims"],
-            "x": put(host_batch["x"]),
-            "labels": put(host_batch["labels"]),
-        }
-        if "report" in host_batch:
-            # host-side observability floats — not a device leaf, and kept
-            # out of the jitted step's pytree (train_step/forward pull
-            # edges/x/labels explicitly)
-            out["report"] = host_batch["report"]
-        return out
+        host = [host_batch["edges"], host_batch["x"], host_batch["labels"]]
+        nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(host))
+        with jax.profiler.TraceAnnotation(
+                "engine.place", batch=host_batch.get("batch", -1),
+                bytes=nbytes):
+            edges, x, labels = jax.tree_util.tree_map(put, host)
+        return {"edges": edges, "dims": host_batch["dims"], "x": x,
+                "labels": labels}
 
     def shard_batch(self, mb, features: np.ndarray, labels: np.ndarray
                     ) -> Dict[str, Any]:
@@ -364,8 +377,9 @@ class EngineBundle:
         n_layers = len(params)
         for l in range(n_layers - 1, -1, -1):
             n_dst, _ = dims[l]
-            h = jnp.dot(h, params[n_layers - 1 - l]["w"],  # combination
-                        precision=F32_MATMUL)
+            with jax.named_scope("combine"):
+                h = jnp.dot(h, params[n_layers - 1 - l]["w"],
+                            precision=F32_MATMUL)
             h = self.format.device_aggregate(
                 self.config.schedule, self.axis, self.ndim, n_dst,
                 edges[l], h, self.n_chunks, topology=self.config.topology)

@@ -60,6 +60,7 @@ class MiniBatch:
     input_nodes: np.ndarray          # [n_last_padded] global ids of frontier
     seed_nodes: np.ndarray           # [batch] global ids of the batch
     n_real: Tuple[int, ...]          # true (unpadded) node count per hop
+    batch: int = -1                  # the pipeline's batch index (-1: none)
 
 
 class NeighborSampler:

@@ -242,7 +242,7 @@ class Trainer:
         """The store stage of the staged chain: frontier rows out of the
         feature store, through the hot-vertex cache when one is enabled."""
         feats = gather_features(self._gather_src, mb.input_nodes,
-                                self.dataset.graph.n_nodes)
+                                self.dataset.graph.n_nodes, mb.batch)
         return mb, feats, labels
 
     def _next_batch(self) -> Dict[str, Any]:
@@ -339,10 +339,6 @@ class Trainer:
         losses: List[float] = []
         for _ in range(n_steps):
             batch = self._next_batch()
-            if isinstance(batch.get("report"), dict):
-                # partition/merge observability from the bundle's host-side
-                # batch prep (wire bytes, virtual vertices, pair coverage)
-                self.last_plan_report = dict(batch["report"])
             self.params, loss = self.bundle.train_step(self.params, batch)
             losses.append(float(loss))
             self.global_step += 1
@@ -447,11 +443,6 @@ class Trainer:
             out["gather_bytes"] = int(self.store.bytes_gathered)
             if self.cache is not None:
                 out["cache"] = self.cache.stats()
-        if getattr(self, "last_plan_report", None):
-            # last train batch's partition/merge plan metrics, next to the
-            # cache stats: measured exchange wire bytes (per core, summed
-            # over hop layers), mined virtual vertices, and pair coverage
-            out["plan"] = dict(self.last_plan_report)
         if isinstance(self.fetcher, StagedPrefetcher):
             # last epoch's per-stage stalls (stage k's stall = time it
             # waited on stage k-1 — where the chain is bottlenecked)

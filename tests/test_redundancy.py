@@ -402,7 +402,7 @@ _SWEEP = """
         for _ in range(5):
             p, loss = bundle.train_step(p, bb)
             traj.append(float(loss))
-        return traj, bb
+        return traj, bundle
 
     ref, _ = trajectory(EngineConfig.from_spec('coo+serial', lr=0.3))
     n_ran = 0
@@ -415,10 +415,11 @@ _SWEEP = """
                 Engine(cfg).build(mesh)
             except ValueError:
                 continue          # topology rejects this core count
-            traj, bb = trajectory(cfg)
+            traj, bundle = trajectory(cfg)
             for i, (a, b) in enumerate(zip(ref, traj)):
                 assert abs(a - b) <= 1e-5, (cfg.spec, i, a, b)
-            reports[(spec, partition)] = bb['report']
+            reports[(spec, partition)] = bundle.plan_report(
+                _MB(), feats.shape[-1])
             n_ran += 1
     assert n_ran >= 12, n_ran
     # the redundancy tier actually engaged on the ELL specs...
