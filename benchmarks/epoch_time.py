@@ -969,10 +969,11 @@ def measured_input_pipeline(n_cores: int = 4, spec: str = "ell+pipelined",
                             seed: int = 0,
                             modes=("sync", "prefetch")) -> Dict:
     """Per-step host-stall time of the engine-native Trainer under each
-    input pipeline.  ``sync`` pays sampling + per-batch layout build +
-    placement inline on the step path; ``prefetch`` runs the identical
-    work on the Trainer's producer thread (depth-2 double buffering), so
-    its stall is only the queue wait the device step failed to hide.  Both
+    input pipeline.  ``sync`` pays sampling + feature gather + per-batch
+    layout build + placement inline on the step path; ``prefetch`` runs
+    the identical work as the Trainer's staged chain (each stage on its
+    own thread, depth-2 queues), so its stall is only the queue wait the
+    device step failed to hide.  Both
     modes consume the SAME deterministic batch stream (seeded pipeline),
     so their loss trajectories must match bit-for-bit — recorded as
     ``input_loss_match``.  Warmup steps absorb the jit compiles (shape

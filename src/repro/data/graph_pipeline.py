@@ -1,6 +1,7 @@
 """Graph mini-batch pipeline: sampler → static-shaped device batches — plus
-the background :class:`Prefetcher` that takes the host-side work off the
-step critical path.
+the background producers (:class:`Prefetcher`, :class:`StagedPrefetcher`,
+:class:`BatchChain`) that take the host-side work off the step critical
+path.
 
 Wraps :class:`repro.graph.NeighborSampler` into the same restartable-stream
 contract as the token pipeline: the epoch permutation is derived from
@@ -9,14 +10,15 @@ batches.  Shapes are padded to the per-layer static maxima so one jit trace
 serves every batch (the paper's fixed 1024-node staging serves the same
 purpose in BRAM).
 
-:class:`Prefetcher` is the software analogue of the paper's NUMA-aware
-host-side staging (§4.2–4.3): sampling + per-batch layout building +
-device placement run on a producer thread with a depth-``k`` bounded queue
-(default 2 — double buffering), so batch *i+1*'s host work overlaps batch
-*i*'s device step instead of stalling it.  It preserves the restartable
-contract: each queue slot carries the pipeline state that regenerates the
-NEXT batch, so checkpointing mid-epoch with batches in flight restores
-batch-exact.
+:class:`BatchChain` is the software analogue of the paper's NUMA-aware
+host-side staging (§4.2–4.3): the four host stages of a batch — sample,
+feature gather, layout build, device placement — each run on a thread of
+their own, joined by depth-``k`` bounded queues, so the host's pace is its
+slowest stage rather than the sum of the four, and batch *i+1*'s host work
+overlaps batch *i*'s device step.  Placement stays at most one batch ahead
+of the step loop.  Every queue slot carries the pipeline state that
+regenerates the NEXT batch, so checkpointing mid-epoch with batches in
+flight restores batch-exact.
 """
 from __future__ import annotations
 
@@ -44,13 +46,19 @@ def sample_batch(dataset: GraphDataset, sampler: NeighborSampler,
     ``[n, c]`` rows alike) with padded seed rows zero-padded — they index
     GLOBAL node 0's label, a placeholder the consumer masks (train loss
     counts only real rows when masked; val accuracy scores only the first
-    ``len(seeds)`` rows).  The staged store pipeline runs this stage alone
-    and gathers features in its own stage."""
+    ``len(seeds)`` rows).  :class:`BatchChain` runs this stage alone and
+    gathers features in its own stage.
+
+    The sampled layers come back as host arrays: the sampler builds them
+    on the default device, and a batch waiting in a host stage's queue
+    must hold no device memory (the layout build reads them on the host
+    in any case)."""
     with jax.profiler.TraceAnnotation("pipeline.sample", batch=batch):
         mb = sampler.sample(seeds, nnz_pad=nnz_pad, rng=rng)
+        layers = jax.device_get(mb.layers)
         pad = mb.layers[0].n_dst - len(seeds)
         labels = dataset.labels[np.pad(seeds, (0, pad))]
-    return dataclasses.replace(mb, batch=batch), labels
+    return dataclasses.replace(mb, layers=layers, batch=batch), labels
 
 
 def gather_features(features, input_nodes: np.ndarray,
@@ -146,10 +154,10 @@ class Prefetcher:
     """Depth-``k`` background producer over a restartable batch source.
 
     ``source`` is any iterator with the pipeline contract (``__next__`` +
-    ``state()``/``restore()``); ``prepare`` is the per-batch host transform
-    (layout build + device placement) run ON THE PRODUCER THREAD, so by the
-    time the train loop calls ``next(prefetcher)`` the batch is device-ready
-    and the only critical-path cost is the queue pop.
+    ``state()``/``restore()``); ``prepare`` is the per-item host transform
+    (one stage of a :class:`StagedPrefetcher`, or a whole batch's host
+    work) run ON THE PRODUCER THREAD, so the consumer's only cost is the
+    queue pop.
 
     Restart contract: every queue slot carries ``source.state()`` captured
     AFTER its batch was drawn — i.e. the state that regenerates the *next*
@@ -186,32 +194,46 @@ class Prefetcher:
     def _produce(self) -> None:
         try:
             while not self._stop.is_set():
-                item = next(self.source)
+                raw = next(self.source)
                 state_after = self.source.state()
+                if not self._await_turn():
+                    return
+                item = raw
                 if self.prepare is not None:
-                    item = self.prepare(*item) if isinstance(item, tuple) \
-                        else self.prepare(item)
-                # bounded put; poll the stop flag so close() never deadlocks
-                # against a full queue
-                while not self._stop.is_set():
-                    try:
-                        self._q.put((state_after, item), timeout=0.05)
-                        break
-                    except queue.Full:
-                        continue
+                    item = self.prepare(*raw) if isinstance(raw, tuple) \
+                        else self.prepare(raw)
+                self._hand(raw, (state_after, item))
         except BaseException as e:  # noqa: BLE001 — re-raised on the consumer
             self._error = e
-            # deliver the sentinel with the same retry-until-stop loop as a
+            # deliver the sentinel with the same retry-until-stop put as a
             # normal item: the queue is usually FULL when the producer dies
             # (device step slower than host work), and dropping the
             # sentinel there would leave the consumer blocked on get()
             # forever with the original exception lost
-            while not self._stop.is_set():
-                try:
-                    self._q.put((None, self._DONE), timeout=0.05)
-                    break
-                except queue.Full:
-                    continue
+            self._put((None, self._DONE))
+
+    def _await_turn(self) -> bool:
+        """Whether the producer may prepare the item it holds (False once
+        stopped); the bounded queue alone paces a plain stage."""
+        return True
+
+    def _hand(self, raw, entry) -> None:
+        """Queue a prepared ``(state_after, item)``; ``raw`` is the item
+        as drawn from the source."""
+        self._put(entry)
+
+    def _put(self, entry) -> None:
+        # bounded put; poll the stop flag so close() never deadlocks
+        # against a full queue
+        while not self._stop.is_set():
+            try:
+                self._q.put(entry, timeout=0.05)
+                return
+            except queue.Full:
+                continue
+
+    def _take(self):
+        return self._q.get()
 
     def _ensure_started(self) -> None:
         if self._thread is None:
@@ -227,7 +249,7 @@ class Prefetcher:
     def __next__(self):
         self._ensure_started()
         t0 = time.perf_counter()
-        state_after, item = self._q.get()
+        state_after, item = self._take()
         self.stall_s += time.perf_counter() - t0
         if item is self._DONE:
             err, self._error = self._error, None
@@ -299,18 +321,68 @@ class Prefetcher:
         self.close()
 
 
+class _Handoff(Prefetcher):
+    """The last stage of a :class:`StagedPrefetcher`: the one that hands
+    finished items to the consumer (placed device batches, in a
+    :class:`BatchChain`).
+
+    It prepares an item only once the consumer has taken the previous
+    one, so at most one finished item waits beside the one the consumer
+    is using: device memory holds the running step's batch plus one more,
+    whatever the queue depth.  Each hand-off runs under the profiler span
+    ``pipeline.handoff``, tagged ``batch`` (the raw item's ``"batch"``
+    entry, -1 without one) and ``ready``: 1 when no consumer was waiting
+    on the queue at that moment, 0 when one was blocked for it."""
+
+    def __init__(self, source, prepare=None, depth: int = 2):
+        super().__init__(source, prepare=prepare, depth=depth)
+        self._taken = threading.Event()    # nothing handed and not taken
+        self._taken.set()
+        self._waiting = threading.Event()  # the consumer is blocked on get
+
+    def _await_turn(self) -> bool:
+        while not self._taken.wait(timeout=0.05):
+            if self._stop.is_set():
+                return False
+        return not self._stop.is_set()
+
+    def _hand(self, raw, entry) -> None:
+        batch = raw.get("batch", -1) if isinstance(raw, dict) else -1
+        ready = int(not self._waiting.is_set())
+        # cleared BEFORE the put: the consumer sets it after its get, so
+        # a take that follows the put at once is never lost
+        self._taken.clear()
+        with jax.profiler.TraceAnnotation("pipeline.handoff", batch=batch,
+                                          ready=ready):
+            self._put(entry)
+
+    def _take(self):
+        self._waiting.set()
+        try:
+            return self._q.get()
+        finally:
+            self._waiting.clear()
+            self._taken.set()
+
+    def close(self) -> None:
+        super().close()
+        self._taken.set()                  # the drained queue holds nothing
+
+
 class StagedPrefetcher:
     """Multi-stage producer chain — the depth-2 double buffer grown into a
     pipeline of named stages, each on its own thread with its own bounded
     queue.
 
     ``stages`` is a sequence of ``(name, fn)``; stage ``k``'s
-    :class:`Prefetcher` consumes stage ``k-1``'s output, so with the store
-    pipeline's ``sample → gather → layout → place`` chain, batch *i+2*'s
-    feature gather overlaps batch *i+1*'s layout build overlaps batch
-    *i*'s device step — the staged analogue of the paper's host-side NUMA
-    staging, with the store's gather latency hidden the same way the
-    layout build already was.
+    :class:`Prefetcher` consumes stage ``k-1``'s output (``fn`` None: the
+    stage only draws from its upstream, so the first stage can run the
+    source itself on a thread of its own).  With :class:`BatchChain`'s
+    ``sample | gather | layout | place``, batch *i+3*'s sampling overlaps
+    batch *i+2*'s feature gather, batch *i+1*'s layout build and
+    placement, and batch *i*'s device step — the staged analogue of the
+    paper's host-side NUMA staging.  The last stage hands over at most one
+    finished item ahead of the consumer (:class:`_Handoff`).
 
     The restartable-stream contract survives the depth: every queue slot
     in every stage carries the SOURCE state that regenerates its batch
@@ -328,16 +400,31 @@ class StagedPrefetcher:
     def __init__(self, source, stages, depth: int = 2):
         if not stages:
             raise ValueError("StagedPrefetcher needs at least one stage")
-        self.source = source
         self.names: Tuple[str, ...] = tuple(name for name, _ in stages)
         if len(set(self.names)) != len(self.names):
             raise ValueError(f"duplicate stage names: {list(self.names)}")
         self.stages: list = []
         cur = source
-        for _, fn in stages:
-            cur = Prefetcher(cur, prepare=fn, depth=depth)
+        for k, (_, fn) in enumerate(stages):
+            stage = _Handoff if k == len(stages) - 1 else Prefetcher
+            cur = stage(cur, prepare=fn, depth=depth)
             self.stages.append(cur)
         self._tail: Prefetcher = cur
+
+    @property
+    def source(self):
+        """The batch source the first stage draws from.  Assigning one
+        closes the chain and starts it afresh at the new source's current
+        state (``restore`` then positions it)."""
+        return self.stages[0].source
+
+    @source.setter
+    def source(self, source) -> None:
+        self.close()
+        self.stages[0].source = source
+        state = source.state()
+        for st in self.stages:
+            st._consumed_state = dict(state)
 
     # -- consumer -----------------------------------------------------------
     def __iter__(self) -> "StagedPrefetcher":
@@ -389,3 +476,34 @@ class StagedPrefetcher:
 
     def __exit__(self, *exc) -> None:
         self.close()
+
+
+class BatchChain(StagedPrefetcher):
+    """The training input chain: ``sample | gather | layout | place``,
+    each stage on its own thread.
+
+    ``source`` yields ``(mb, labels)`` (a :class:`GraphBatchPipeline` with
+    ``defer_gather=True``) and is drawn on the sample stage's thread;
+    ``gather(mb, labels) -> (mb, feats, labels)`` reads the frontier's
+    feature rows (a dense array or an out-of-core store alike);
+    :attr:`prepare` ``(mb, feats, labels)`` builds the host layout;
+    ``place`` puts it on the devices, at most one batch ahead of the step
+    loop.  Sampling and gathering sit on different threads, so the host's
+    pace is its slowest stage, not the sum of the two."""
+
+    STAGES = ("sample", "gather", "layout", "place")
+
+    def __init__(self, source, gather: Callable, prepare: Callable,
+                 place: Callable, depth: int = 2):
+        super().__init__(source, list(zip(
+            self.STAGES, (None, gather, prepare, place))), depth=depth)
+
+    @property
+    def prepare(self) -> Callable:
+        """The layout stage's callable; assigning one replaces it from the
+        next batch on."""
+        return self.stages[2].prepare
+
+    @prepare.setter
+    def prepare(self, fn: Callable) -> None:
+        self.stages[2].prepare = fn

@@ -9,13 +9,15 @@ that split in software.  One :class:`Trainer` owns the whole loop:
     over the hypercube axis, Weight-Bank ``pmean`` sync), so any registered
     spec trains unchanged: ``coo+serial``, ``block+pipelined``,
     ``ell+pipelined``, or a format you registered yesterday.
-  * **Async input pipeline** — sampling, the per-batch layout build
-    (``bundle.prepare_batch`` — the host-side hook that makes
-    ``traceable=False`` formats trainable on sampled graphs) and device
-    placement (``commit_batch``) run on a :class:`~repro.data.Prefetcher`
-    thread with depth-2 double buffering; the step loop's only input cost
-    is a queue pop.  ``input_pipeline="sync"`` runs the same work inline
-    for A/B measurement (``benchmarks/epoch_time.py --input-pipeline``).
+  * **Async input pipeline** — sampling, the feature gather, the
+    per-batch layout build (``bundle.prepare_batch`` — the host-side hook
+    that makes ``traceable=False`` formats trainable on sampled graphs)
+    and device placement (``commit_batch``) run as a
+    :class:`~repro.data.BatchChain`, each stage on its own thread with
+    depth-2 queues between them, placement at most one batch ahead of the
+    step; the step loop's only input cost is a queue pop.
+    ``input_pipeline="sync"`` runs the same work inline for A/B
+    measurement (``benchmarks/epoch_time.py --input-pipeline``).
   * **Epoch metrics** — per-epoch validation accuracy on a held-out seed
     set, wall-clock, steps/s and host-stall time per step.
   * **Checkpoint/resume** — params + progress counters + pipeline state
@@ -41,8 +43,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.configs.gcn_paper import FANOUTS
-from repro.data import (GraphBatchPipeline, Prefetcher, StagedPrefetcher,
-                        gather_features)
+from repro.data import BatchChain, GraphBatchPipeline, gather_features
 from repro.distributed.gcn_train import init_params
 from repro.engine import Engine, EngineConfig
 from repro.featurestore import FeatureStore, HotVertexCache, get_store
@@ -63,19 +64,19 @@ class Trainer:
     n_cores: hypercube size; needs ``len(jax.devices()) >= n_cores``
         (``XLA_FLAGS=--xla_force_host_platform_device_count=P`` on CPU).
         ``mesh`` overrides with a prebuilt mesh.
-    input_pipeline: ``"prefetch"`` (background thread, depth
-        ``prefetch_depth``) or ``"sync"`` (host work inline on the step
-        path — the A/B baseline).
+    input_pipeline: ``"prefetch"`` (the staged chain sample | gather |
+        layout | place, each stage on its own thread with depth
+        ``prefetch_depth`` queues between them, placement at most one
+        batch ahead of the step) or ``"sync"`` (host work inline on the
+        step path — the A/B baseline).
     feature_store: where node features live.  ``None``/``"device"`` keeps
-        the in-memory path (unless the dataset itself is store-backed); a
-        registered backend name (``"host"``, ``"mmap"``, …) wraps the
-        dataset's dense features into that out-of-core store; a
-        :class:`~repro.featurestore.FeatureStore` instance is used as-is.
-        With a store, only each batch's frontier rows stream to the
-        device, and ``input_pipeline="prefetch"`` becomes the STAGED
-        chain sample → gather → layout → place (each stage on its own
-        thread), so the store's gather latency for batch *i+2* hides
-        under batch *i+1*'s layout build and batch *i*'s device step.
+        them in a dense host array (unless the dataset itself is
+        store-backed); a registered backend name (``"host"``, ``"mmap"``,
+        …) wraps the dataset's dense features into that out-of-core
+        store; a :class:`~repro.featurestore.FeatureStore` instance is
+        used as-is.  The two modes differ only in where the gather stage
+        reads; either way only each batch's frontier rows reach the
+        device.
     cache_capacity: rows in the degree-keyed hot-vertex cache in front of
         the store (0 disables); ``cache_pinned`` of them pin the
         top-degree vertices (default: half), the rest are LRU.
@@ -162,7 +163,8 @@ class Trainer:
             indptr = dataset.graph.indptr
             self.cache = HotVertexCache(store, indptr[1:] - indptr[:-1],
                                         cache_capacity, pinned=cache_pinned)
-        self._gather_src = self.cache if self.cache is not None else store
+        self._gather_src = self.cache if self.cache is not None \
+            else store if store is not None else feats
         self.feature_mode = "device" if store is None \
             else getattr(store, "name", "custom")
         if mesh is None:
@@ -192,24 +194,19 @@ class Trainer:
                 "batch or raise the dataset scale")
         self.sampler = NeighborSampler(dataset.graph, fanouts=fanouts,
                                        pad_multiple=pad, seed=seed)
+        # the gather is always its own stage (sync mode calls it inline)
         self.pipeline = GraphBatchPipeline(dataset, self.sampler,
                                            batch_size, seed=seed,
-                                           defer_gather=store is not None)
+                                           defer_gather=True)
         self._nnz_pad = self.sampler.static_nnz(batch_size)
-        if input_pipeline != "prefetch":
-            self.fetcher = None
-        elif store is not None:
-            # staged chain: batch i+2's store gather hides under batch
-            # i+1's layout build, which hides under batch i's device step
-            self.fetcher = StagedPrefetcher(
-                self.pipeline,
-                [("gather", self._gather_stage),
-                 ("layout", self.bundle.prepare_batch),
-                 ("place", self.bundle.commit_batch)],
+        self.fetcher: Optional[BatchChain] = None
+        if input_pipeline == "prefetch":
+            # the bundle is looked up per batch: a resume may rebind it
+            self.fetcher = BatchChain(
+                self.pipeline, self._gather_stage,
+                lambda *item: self.bundle.prepare_batch(*item),
+                lambda host: self.bundle.commit_batch(host),
                 depth=prefetch_depth)
-        else:
-            self.fetcher = Prefetcher(self.pipeline, prepare=self._prepare,
-                                      depth=prefetch_depth)
         # model: one GCN layer per sampled hop, hidden width between
         feat = dataset.features.shape[1]
         dims = [feat] + [hidden] * (len(fanouts) - 1) \
@@ -234,13 +231,15 @@ class Trainer:
 
     # -- input pipeline ------------------------------------------------------
     def _prepare(self, mb, feats, labels) -> Dict[str, Any]:
-        """sample → host layout build → device placement (producer side)."""
+        """Host layout build → device placement, inline: the sync path
+        and the validation batches."""
         return self.bundle.commit_batch(
             self.bundle.prepare_batch(mb, feats, labels))
 
     def _gather_stage(self, mb, labels):
-        """The store stage of the staged chain: frontier rows out of the
-        feature store, through the hot-vertex cache when one is enabled."""
+        """The gather stage: frontier rows out of the dense features or
+        the feature store, through the hot-vertex cache when one is
+        enabled."""
         feats = gather_features(self._gather_src, mb.input_nodes,
                                 self.dataset.graph.n_nodes, mb.batch)
         return mb, feats, labels
@@ -249,10 +248,7 @@ class Trainer:
         if self.fetcher is not None:
             return next(self.fetcher)
         t0 = time.perf_counter()
-        item = next(self.pipeline)
-        if self.store is not None:     # defer_gather stream: (mb, labels)
-            item = self._gather_stage(*item)
-        batch = self._prepare(*item)
+        batch = self._prepare(*self._gather_stage(*next(self.pipeline)))
         self._sync_stall_s += time.perf_counter() - t0
         self._sync_steps += 1
         return batch
@@ -338,8 +334,10 @@ class Trainer:
         """Run ``n_steps`` optimizer steps; returns their losses."""
         losses: List[float] = []
         for _ in range(n_steps):
-            batch = self._next_batch()
-            self.params, loss = self.bundle.train_step(self.params, batch)
+            # no reference to the batch outlives its step: the chain places
+            # the next one while this one runs
+            self.params, loss = self.bundle.train_step(
+                self.params, self._next_batch())
             losses.append(float(loss))
             self.global_step += 1
             if self.log_every and self.global_step % self.log_every == 0:
@@ -443,7 +441,7 @@ class Trainer:
             out["gather_bytes"] = int(self.store.bytes_gathered)
             if self.cache is not None:
                 out["cache"] = self.cache.stats()
-        if isinstance(self.fetcher, StagedPrefetcher):
+        if self.fetcher is not None:
             # last epoch's per-stage stalls (stage k's stall = time it
             # waited on stage k-1 — where the chain is bottlenecked)
             out["stage_stall_s_per_step"] = self.fetcher.stage_stalls()

@@ -336,8 +336,35 @@ def test_trainer_store_streams_match_dense_bit_exact():
         assert out["cache"]["hit_rate"] > 0
     # the staged chain reports per-stage stalls; sync has no chain
     assert set(staged["stage_stall_s_per_step"]) \
-        == {"gather", "layout", "place"}
+        == {"sample", "gather", "layout", "place"}
     assert "stage_stall_s_per_step" not in sync
+
+
+def test_staged_store_chain_places_at_most_one_batch_ahead():
+    """The store path's chain keeps the same cap: with a step loop slower
+    than the chain, ``commit_batch`` runs at most one batch ahead of the
+    batches consumed."""
+    import time
+    tr = _store_trainer("prefetch", feature_store="mmap", cache_capacity=32)
+    commit, calls = tr.bundle.commit_batch, []
+
+    def counting(host):
+        calls.append(host["batch"])
+        return commit(host)
+
+    tr.bundle.commit_batch = counting
+    ahead = []
+    for consumed in range(1, 5):
+        next(tr.fetcher)
+        # the step: the chain places the next batch, and runs on as far
+        # as it may
+        deadline = time.monotonic() + 20
+        while len(calls) <= consumed and time.monotonic() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.5)
+        ahead.append(len(calls) - consumed)
+    tr.close()
+    assert ahead == [1] * 4, ahead
 
 
 def test_trainer_trains_from_store_backed_dataset():

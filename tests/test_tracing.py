@@ -1,9 +1,11 @@
 """The program's profiler spans and named scopes.
 
-The producer thread marks each batch's four host stages with
+The input chain's four stage threads mark each batch's host stages with
 ``jax.profiler.TraceAnnotation`` spans (``pipeline.sample``,
 ``pipeline.gather``, ``engine.layout``, ``engine.place``), each tagged with
-the pipeline's batch index, the place span also with the bytes it places.
+the pipeline's batch index, the place span also with the bytes it places;
+the place thread marks each hand-off to the step loop
+(``pipeline.handoff``).
 The train step names two device layers with ``jax.named_scope``:
 ``combine`` (the combination ``h @ W``) and ``mirror_backward`` (the ELL
 aggregation's custom-vjp backward).  The chip benchmark's per-layer
@@ -66,13 +68,20 @@ def test_producer_spans_one_per_batch(tmp_path):
 
     spans = _host_spans(tmp_path)
     by_name = {name: [s for s in spans if s[1] == name] for name in STAGES}
-    # every batch the producer made: the three consumed and those in flight
-    assert len(placed) >= 3
-    for name, found in by_name.items():
-        assert sorted(s[3]["batch"] for s in found) == sorted(placed), name
+    # every batch placed: the three consumed and at most one ahead
+    assert set(range(3)) <= set(placed) and len(placed) <= 3 + 1
+    # each stage saw every batch the next one did, and no batch twice;
+    # the place spans are exactly the batches placed
+    tagged = [[s[3]["batch"] for s in by_name[name]] for name in STAGES]
+    for up, down in zip(tagged, tagged[1:]):
+        assert len(set(up)) == len(up) and set(down) <= set(up)
+    assert sorted(tagged[-1]) == sorted(placed)
+    # each stage on a thread of its own, none of them the consumer's
     [consumer] = {s[0] for s in spans if s[1] == "test.consumer"}
-    threads = {s[0] for found in by_name.values() for s in found}
-    assert len(threads) == 1 and consumer not in threads
+    threads = [{s[0] for s in found} for found in by_name.values()]
+    assert all(len(t) == 1 for t in threads)
+    assert len(set.union(*threads)) == len(STAGES)
+    assert consumer not in set.union(*threads)
     for batch, out in placed.items():
         starts = [next(s[2] for s in by_name[name] if s[3]["batch"] == batch)
                   for name in STAGES]
@@ -82,7 +91,15 @@ def test_producer_spans_one_per_batch(tmp_path):
         leaves = jax.tree_util.tree_leaves(
             [out["edges"], out["x"], out["labels"]])
         assert place[3]["bytes"] == sum(a.nbytes for a in leaves) > 0
-
+    # one hand-off per batch placed, on the place thread, after its place
+    handoffs = [s for s in spans if s[1] == "pipeline.handoff"]
+    assert sorted(s[3]["batch"] for s in handoffs) == sorted(placed)
+    assert {s[0] for s in handoffs} == threads[-1]
+    for h in handoffs:
+        assert h[3]["ready"] in (0, 1)
+        [place] = [s for s in by_name["engine.place"]
+                   if s[3]["batch"] == h[3]["batch"]]
+        assert h[2] >= place[2]
 
 _LOWERED = """
 import json, re

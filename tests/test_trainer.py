@@ -5,6 +5,9 @@ Contracts:
     contract — ordering, state of the last CONSUMED batch (in-flight work
     excluded), drain-on-close with rewind, producer errors re-raised on
     the consumer;
+  * the :class:`~repro.data.BatchChain` places at most one batch ahead of
+    the consumer, marks each hand-off ``ready`` or not, and runs a source
+    or layout callable assigned after construction;
   * sync and prefetch input pipelines consume bit-identical batch streams
     (same losses, step for step);
   * checkpoint mid-epoch WITH batches in flight + restore replays the
@@ -170,6 +173,88 @@ def test_prefetcher_close_after_producer_error_discards_it():
 
 
 # ---------------------------------------------------------------------------
+# BatchChain: one placed batch ahead at most, and the hand-off span.
+# ---------------------------------------------------------------------------
+class _Spans:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each span's
+    name and arguments."""
+
+    def __init__(self):
+        self.seen = []
+
+    def __call__(self, name, **kw):
+        import contextlib
+        self.seen.append((name, kw))
+        return contextlib.nullcontext()
+
+    def of(self, name):
+        return [kw for n, kw in self.seen if n == name]
+
+
+def _chain(place_sleep=0.0, depth=2):
+    """A BatchChain over the count source: batch ``i`` comes out as
+    ``{"batch": i, "f": 10 * i}``; ``placed`` lists the place stage's
+    calls."""
+    import time
+    from repro.data import BatchChain
+    placed = []
+
+    def place(host):
+        placed.append(host["batch"])
+        time.sleep(place_sleep)
+        return dict(host)
+
+    chain = BatchChain(_CountSource(), lambda i: (i, i * 10),
+                       lambda i, f: {"batch": i, "f": f}, place, depth=depth)
+    return chain, placed
+
+
+@pytest.mark.parametrize("depth", [1, 2, 4])
+def test_chain_places_at_most_one_batch_ahead_of_a_slow_consumer(depth):
+    import time
+    chain, placed = _chain(depth=depth)
+    ahead = []
+    for i in range(6):
+        assert next(chain)["batch"] == i
+        time.sleep(0.05)                 # the step: the chain runs ahead
+        ahead.append(len(placed) - (i + 1))
+    chain.close()
+    # the host stages ran ahead by their queues; placement by one batch
+    assert max(ahead) == 1 and min(ahead) >= 0, ahead
+    assert chain.source.idx == 6         # rewound to the last consumed
+
+
+def test_chain_handoff_is_ready_for_a_slow_consumer(monkeypatch):
+    import time
+    import jax
+    spans = _Spans()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", spans)
+    chain, _ = _chain()
+    for i in range(5):
+        assert next(chain)["batch"] == i
+        time.sleep(0.1)
+    chain.close()
+    handoffs = spans.of("pipeline.handoff")
+    assert [h["batch"] for h in handoffs[:5]] == list(range(5))
+    # the first hand-off meets the loop waiting for it; every later batch
+    # is placed while the loop is busy
+    assert [h["ready"] for h in handoffs[1:]] == [1] * (len(handoffs) - 1)
+
+
+def test_chain_handoff_is_not_ready_for_a_slow_producer(monkeypatch):
+    import jax
+    spans = _Spans()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", spans)
+    chain, _ = _chain(place_sleep=0.15)
+    got = [next(chain)["batch"] for _ in range(4)]
+    chain.close()
+    assert got == [0, 1, 2, 3]
+    handoffs = spans.of("pipeline.handoff")
+    assert [h["batch"] for h in handoffs] == got
+    assert [h["ready"] for h in handoffs] == [0] * 4
+
+
+# ---------------------------------------------------------------------------
 # Trainer: sync == prefetch, metrics, multilabel.
 # ---------------------------------------------------------------------------
 def _toy_trainer(pipeline: str, ckpt=None, spec="coo+serial", seed=3,
@@ -187,12 +272,102 @@ def test_trainer_sync_and_prefetch_streams_are_identical():
     a = _toy_trainer("prefetch").fit(1, steps_per_epoch=6)
     b = _toy_trainer("sync").fit(1, steps_per_epoch=6)
     assert a["loss_history"] == b["loss_history"]
+    # dense features run through the staged chain, as a store does
+    assert set(a["stage_stall_s_per_step"]) == {"sample", "gather",
+                                                "layout", "place"}
     assert len(a["loss_history"]) == 6
     assert 0.0 <= a["val_acc"][0] <= 1.0
     for key in ("epoch_s", "steps_per_s", "host_stall_s_per_step"):
         assert len(a[key]) == 1 and a[key][0] >= 0.0
     assert a["input_pipeline"] == "prefetch"
     assert b["input_pipeline"] == "sync"
+
+
+def test_trainer_chain_source_and_prepare_can_be_replaced():
+    """A source assigned to ``fetcher.source`` after construction is what
+    the chain draws from, and a wrapped ``fetcher.prepare`` is what runs,
+    called with ``(mb, feats, labels)``; the loop's stall is the wait on
+    the last stage."""
+    tr = _toy_trainer("prefetch")
+    ref = _toy_trainer("sync")
+
+    class Replay:
+        def __init__(self, pipeline, order):
+            self.pipeline, self.order, self.i = pipeline, order, 0
+
+        def __next__(self):
+            self.pipeline.restore({"seed": self.pipeline.seed, "epoch": 0,
+                                   "batch_idx": self.order[self.i]})
+            self.i += 1
+            return next(self.pipeline)
+
+        def state(self):
+            return {"i": self.i}
+
+        def restore(self, st):
+            self.i = int(st.get("i", 0))
+
+    order = [4, 1, 3]
+    tr.fetcher.source = Replay(tr.pipeline, order)
+    tr.fetcher.restore({"i": 0})
+    prepare, seen = tr.fetcher.prepare, []
+
+    def wrapped(*item):
+        seen.append(item)
+        return prepare(*item)
+
+    tr.fetcher.prepare = wrapped
+    tr.reset_stall_stats()
+    losses = tr.train_steps(3)
+    assert tr.stall_per_step == tr.fetcher.stall_per_step >= 0.0
+    tr.close()
+    assert [len(item) for item in seen[:3]] == [3, 3, 3]
+    assert [item[0].batch for item in seen[:3]] == order
+    # the same batches, drawn by the sync path, train to the same losses
+    want = []
+    for idx in order:
+        ref.pipeline.restore({"seed": ref.pipeline.seed, "epoch": 0,
+                              "batch_idx": idx})
+        want += ref.train_steps(1)
+    assert losses == want
+
+
+def test_trainer_places_at_most_one_batch_ahead():
+    """With a step loop slower than the chain, ``commit_batch`` calls
+    never run more than one ahead of the batches consumed."""
+    import time
+    tr = _toy_trainer("prefetch")
+    commit, calls = tr.bundle.commit_batch, []
+
+    def counting(host):
+        calls.append(host["batch"])
+        return commit(host)
+
+    tr.bundle.commit_batch = counting
+    ahead = []
+    for consumed in range(1, 5):
+        next(tr.fetcher)
+        # the step: the chain places the next batch, and runs on as far
+        # as it may
+        deadline = time.monotonic() + 20
+        while len(calls) <= consumed and time.monotonic() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.5)
+        ahead.append(len(calls) - consumed)
+    tr.close()
+    assert ahead == [1] * 4, ahead
+
+
+def test_sampled_batches_hold_no_device_arrays():
+    """A batch queued between the chain's host stages holds only host
+    arrays: the sampled layers leave the device in the sample stage."""
+    import jax
+    tr = _toy_trainer("prefetch")
+    mb, labels = next(tr.pipeline)
+    tr.close()
+    leaves = jax.tree_util.tree_leaves(mb.layers) + [labels,
+                                                     mb.input_nodes]
+    assert leaves and all(type(a) is np.ndarray for a in leaves)
 
 
 def test_trainer_multilabel_dataset_trains():
@@ -215,6 +390,7 @@ def test_trainer_resume_through_prefetcher_is_bit_exact(tmp_path):
     """Checkpoint mid-epoch while the producer holds prefetched batches in
     flight; restore must replay the exact remaining batch stream (pipeline
     states step for step) and the exact loss trajectory."""
+    from repro.data import BatchChain
     full = _toy_trainer("prefetch")
     full_losses, full_states = [], []
     for _ in range(10):
@@ -223,6 +399,7 @@ def test_trainer_resume_through_prefetcher_is_bit_exact(tmp_path):
     full.close()
 
     part = _toy_trainer("prefetch", ckpt=str(tmp_path))
+    assert isinstance(part.fetcher, BatchChain)
     part.train_steps(4)
     # the producer thread has had time to run ahead; the saved state must
     # nevertheless point at batch 5 (last consumed), not at the queue head
