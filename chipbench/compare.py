@@ -8,13 +8,13 @@ the same weights over the same batches:
 * ``grad_norm_gap`` — the first gradient as SGD received it, worked out
   from each side's own float32 weights after one step,
   ``(w0 − w1) / lr`` (in float64 on the host): the program's norm against
-  the reference's, for the output layer's weight.  Both sides round their
-  weights to float32 alike, so the rounding, which is up to a few
-  thousandths of a step's change, mostly cancels.  Only the output layer:
-  every other weight's gradient passes a ReLU mask, and on a few seeds a
-  pre-activation within rounding of zero falls on the other side of it in
-  the program than in the reference, which moves that gradient by up to
-  ~1e-5 with both sides sound;
+  the reference's, for the output layer's leaves (the model's
+  ``output_leaves``).  Both sides round their weights to float32 alike, so
+  the rounding, which is up to a few thousandths of a step's change, mostly
+  cancels.  Only the output layer: every other weight's gradient passes a
+  ReLU mask, and on a few seeds a pre-activation within rounding of zero
+  falls on the other side of it in the program than in the reference,
+  which moves that gradient by up to ~1e-5 with both sides sound;
 * ``update_norm_gap`` — the norm of ``w_last − w0`` after the compared
   steps against the reference's, leaf by leaf.
 
@@ -49,10 +49,11 @@ def _worst_gap(got, want, keep, pick) -> float:
     return worst if math.isfinite(worst) else math.inf
 
 
-def numbers(prog: dict, ref: dict, lr: float) -> dict:
-    """``prog``: ``losses`` of the compared steps and host weights ``w0``,
+def numbers(prog: dict, ref: dict, lr: float, output: list) -> dict:
+    """``prog``: ``losses`` of the compared steps and host leaves ``w0``,
     ``w1`` (after the first step) and ``w_last``; ``ref``: the output of
-    :func:`chipbench.reference.sgd_steps` over the same batches."""
+    :func:`chipbench.reference.sgd_steps` over the same batches;
+    ``output``: which leaves are the output layer's."""
     gaps = [abs(a - b) / abs(b) for a, b in zip(prog["losses"],
                                                ref["losses"])]
     loss_gap = max(gaps) if all(map(math.isfinite, gaps)) else math.inf
@@ -66,7 +67,7 @@ def numbers(prog: dict, ref: dict, lr: float) -> dict:
                        for a, b in zip(w0, w1)])
 
     grad_prog, grad_ref = grad(prog["w1"]), grad(ref["params"][0])
-    last = [False] * (len(keep) - 1) + [keep[-1]]
+    last = [k and o for k, o in zip(keep, output)]
     upd_prog = _norms([np.asarray(b, np.float64) - a
                        for a, b in zip(w0, prog["w_last"])])
     upd_ref = _norms([np.asarray(b, np.float64) - a

@@ -6,7 +6,7 @@ Trainer's own loop (see :class:`chipbench.train.TrainCell`):
 
 * ``state_unchanged`` — the engine's step runs, its new weights are thrown
   away;
-* ``reference(precision, keep)`` — the plain reference put in the
+* ``reference(precision, keep)`` — the model's plain reference put in the
   program's place: at a lower precision it is the control; with
   ``keep=0.5`` it leaves half of the batch out and takes the mean over the
   rest.
@@ -26,13 +26,13 @@ def state_unchanged(cell, params, batch):
 
 
 def reference(precision: str = "highest", keep: float = 1.0):
-    from chipbench import reference as ref
-
     def hook(cell, params, batch):
         b = cell.replay.batch_at(cell.steps_done)
-        ws = [p["w"] for p in params]
-        loss, grads = ref.loss_and_grad(ws, b, cell.data, precision, keep)
-        return [{"w": w - cell.lr * g} for w, g in zip(ws, grads)], loss
+        model = cell.model
+        ws = model.from_program(params)
+        loss, grads = model.loss_and_grad(ws, b, cell.data, precision, keep)
+        return model.to_program([w - cell.lr * g
+                                 for w, g in zip(ws, grads)]), loss
 
     return hook
 

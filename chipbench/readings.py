@@ -46,6 +46,7 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     files = run.cell_files(args.workload)
     cell, cfg, traffic = files["cell"], files["cfg"], files["traffic"]
+    model = run.load_model(cfg["model"])
     if run.find_chips(cell["chips"], require_chip=True) is None:
         return 1
     from repro.kernels import tune
@@ -55,27 +56,28 @@ def main(argv=None) -> int:
     compared = traffic["compared_steps"]
     with (faults.drop_exchange() if args.drop_exchange
           else contextlib.nullcontext()):
-        tc = TrainCell(cfg, traffic, cell["chips"], data)
+        tc = TrainCell(cfg, traffic, cell["chips"], data, model)
         for seed in [int(s) for s in args.seeds.split(",")]:
             tc.reseed(seed)
             prog = tc.warm(compared)
-            ref = reference.sgd_steps(prog["w0"], prog["batches"], data,
-                                      tc.lr)
+            output = model.output_leaves(len(prog["w0"]))
+            ref = reference.sgd_steps(model.loss_and_grad, prog["w0"],
+                                      prog["batches"], data, tc.lr)
             kind = "drop_exchange" if args.drop_exchange else "program"
             _emit(args.workload, seed, kind,
-                  compare.numbers(prog, ref, tc.lr))
+                  compare.numbers(prog, ref, tc.lr, output))
             others = [(c, {"precision": c})
                       for c in args.controls.split(",") if c]
             if args.keep is not None:
                 others.append((f"keep_{args.keep:g}", {"keep": args.keep}))
             for name, kw in others:
-                alt = reference.sgd_steps(prog["w0"], prog["batches"], data,
-                                          tc.lr, **kw)
+                alt = reference.sgd_steps(model.loss_and_grad, prog["w0"],
+                                          prog["batches"], data, tc.lr, **kw)
                 as_prog = {"losses": alt["losses"], "w0": prog["w0"],
                            "w1": alt["params"][0],
                            "w_last": alt["params"][-1]}
                 _emit(args.workload, seed, name,
-                      compare.numbers(as_prog, ref, tc.lr))
+                      compare.numbers(as_prog, ref, tc.lr, output))
         tc.close()
     return 0
 

@@ -1,17 +1,11 @@
-"""The plain float32 reference: a GCN over the sampled batch, written with
-``segment_sum`` and nothing of the program's layouts, and its SGD steps.
+"""What every model's plain reference shares: the batch's inputs as the
+sampler drew them, the products at each precision, and the SGD steps.
 
-Per hop, deepest first (the engine's CoAg order)::
-
-    h = h @ W                                      # combination
-    h = segment_sum(v[e] * h[col[e]], row[e])      # mean aggregation
-    h = relu(h)                                    # between hops
-
-with ``v[e] = 1 / (entries of row[e])``, self-loops included and repeated
-samples counted, and a mean cross-entropy over the batch's roots.  From the
-timed path it takes only what the sampler chose: which global nodes each
-hop holds and which (row, col) pairs it drew.  Features and labels come
-from the benchmark's own data, weights from the benchmark's own init.
+A model's own forward and loss live in ``chipbench/models/<model>.py`` and
+take from the timed path only what the sampler chose: which global nodes
+each hop holds and which (row, col) pairs it drew (:func:`batch_inputs`).
+Features and labels come from the benchmark's own data, weights from the
+model's own init.
 
 ``precision`` picks how every product is computed:
 
@@ -20,8 +14,11 @@ from the benchmark's own data, weights from the benchmark's own init.
   ``Precision.HIGH``;
 * ``"bf16"`` — one bf16 pass, TPU's ``Precision.DEFAULT`` for float32.
 
-The lower two are emulated with explicit bf16 splits, forward and backward,
-so they read the same on a CPU as on the chip: they are the controls.
+A model computes its matmuls with :func:`mm` at ``"highest"`` and with
+:func:`low_dot` below it, and its elementwise scalings with
+:func:`low_scale` below it.  The lower two are emulated with explicit bf16
+splits, forward and backward, so they read the same on a CPU as on the
+chip: they are the controls.
 """
 from __future__ import annotations
 
@@ -38,15 +35,6 @@ PASSES = {"high": 3, "bf16": 1}
 def seed_words(seed: int) -> np.ndarray:
     """A random key's two 32-bit words, drawn from a seed of any size."""
     return np.random.SeedSequence(int(seed)).generate_state(2)
-
-
-def init_params(words, dims_io) -> list:
-    """Normal weights scaled by ``d_in ** -0.5``, one float32 matrix per
-    layer, from a key's words (:func:`seed_words`)."""
-    key = jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32))
-    keys = jax.random.split(key, len(dims_io))
-    return [jax.random.normal(k, (a, b), jnp.float32) * a ** -0.5
-            for k, (a, b) in zip(keys, dims_io)]
 
 
 def _bf16(x):
@@ -72,29 +60,29 @@ def _prod(op, a, b, passes: int):
     return out
 
 
-def _mm(a, b):
+def mm(a, b):
     return jnp.dot(a, b, precision=HIGHEST)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _low_dot(a, b, passes):
-    return _prod(_mm, a, b, passes)
+def low_dot(a, b, passes):
+    return _prod(mm, a, b, passes)
 
 
 def _low_dot_fwd(a, b, passes):
-    return _prod(_mm, a, b, passes), (a, b)
+    return _prod(mm, a, b, passes), (a, b)
 
 
 def _low_dot_bwd(passes, res, ct):
     a, b = res
-    return _prod(_mm, ct, b.T, passes), _prod(_mm, a.T, ct, passes)
+    return _prod(mm, ct, b.T, passes), _prod(mm, a.T, ct, passes)
 
 
-_low_dot.defvjp(_low_dot_fwd, _low_dot_bwd)
+low_dot.defvjp(_low_dot_fwd, _low_dot_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _low_scale(v, h, passes):
+def low_scale(v, h, passes):
     return _prod(jnp.multiply, v, h, passes)
 
 
@@ -106,31 +94,7 @@ def _low_scale_bwd(passes, v, ct):
     return jnp.zeros_like(v), _prod(jnp.multiply, v, ct, passes)
 
 
-_low_scale.defvjp(_low_scale_fwd, _low_scale_bwd)
-
-
-@functools.partial(jax.jit, static_argnames=("n_dst", "precision", "keep"))
-def _loss_and_grad(ws, x, y, rows, cols, vals, *, n_dst, precision, keep):
-    passes = PASSES.get(precision)
-
-    def loss_fn(ws):
-        h = x
-        n_hops = len(rows)
-        for l in range(n_hops - 1, -1, -1):
-            w = ws[n_hops - 1 - l]
-            h = _mm(h, w) if passes is None else _low_dot(h, w, passes)
-            g = h[cols[l]]
-            v = vals[l][:, None]
-            msg = v * g if passes is None else _low_scale(
-                jnp.broadcast_to(v, g.shape), g, passes)
-            h = jax.ops.segment_sum(msg, rows[l], num_segments=n_dst[l])
-            if l:
-                h = jnp.maximum(h, 0.0)
-        logp = jax.nn.log_softmax(h, -1)
-        nll = -jnp.take_along_axis(logp, y[:, None], -1)[:, 0]
-        return nll[:keep].mean()
-
-    return jax.value_and_grad(loss_fn)(ws)
+low_scale.defvjp(_low_scale_fwd, _low_scale_bwd)
 
 
 def batch_inputs(batch: dict, data: dict):
@@ -153,21 +117,12 @@ def batch_inputs(batch: dict, data: dict):
             tuple(vals), tuple(int(h["n_dst"]) for h in hops))
 
 
-def loss_and_grad(ws, batch: dict, data: dict, precision: str = "highest",
-                  keep: float = 1.0):
-    """(mean loss over the first ``keep`` share of the roots, its gradient
-    with respect to each weight)."""
-    x, y, rows, cols, vals, n_dst = batch_inputs(batch, data)
-    return _loss_and_grad(list(ws), x, y, rows, cols, vals, n_dst=n_dst,
-                          precision=precision,
-                          keep=int(round(batch["n_roots"] * keep)))
-
-
-def sgd_steps(ws0, batches, data: dict, lr: float, *,
+def sgd_steps(loss_and_grad, ws0, batches, data: dict, lr: float, *,
               precision: str = "highest", keep: float = 1.0) -> dict:
-    """The reference's own trajectory from ``ws0`` over ``batches``: each
-    step's loss, the first step's gradient, the weights after each step
-    (host float32 arrays)."""
+    """The reference's own trajectory from the leaves ``ws0`` over
+    ``batches``, by the model's ``loss_and_grad``: each step's loss, the
+    first step's gradient, the leaves after each step (host float32
+    arrays)."""
     ws = [jnp.asarray(w, jnp.float32) for w in ws0]
     out = {"losses": [], "grads1": None, "params": []}
     for batch in batches:

@@ -7,10 +7,11 @@
 The cell, its configuration and its traffic are found by name:
 ``BENCHMARK.json`` names the cell's ``config`` and ``traffic``, whose files
 are ``chipbench/configs/<config>.json`` and ``chipbench/traffic/<traffic>
-.json``; the limits of its ``correct`` are ``chipbench/limits/<cell>.json``;
-each metric is read by ``chipbench/metrics/<metric>.py``.  A new cell,
-configuration, traffic mix or metric is new files and entries, with no
-edit here.
+.json``; the configuration's ``model`` names ``chipbench/models/<model>.py``
+(its weights, plain reference and work count, see :func:`load_model`); the
+limits of its ``correct`` are ``chipbench/limits/<cell>.json``; each metric
+is read by ``chipbench/metrics/<metric>.py``.  A new cell, configuration,
+model, traffic mix or metric is new files and entries, with no edit here.
 
 A run: set-up (the configuration's data, the program's Trainer, one step on
 each batch the window will use, loaded from the persistent cache), then the
@@ -51,6 +52,11 @@ if __name__ == "__main__":
     sys.path.insert(1, os.path.join(ROOT, "src"))
 
 BENCH = os.path.join(ROOT, "chipbench")
+#: where :func:`load_model` finds a configuration's model
+MODELS = os.path.join(BENCH, "models")
+#: what a model module provides (see :func:`load_model`)
+MODEL_FUNCTIONS = ("init", "to_program", "from_program", "trainer_kwargs",
+                   "loss_and_grad", "output_leaves", "step_work")
 #: JAX's persistent compilation cache where ``JAX_COMPILATION_CACHE_DIR``
 #: is not set: a fixed place in the checkout
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
@@ -131,13 +137,55 @@ def metrics_for(spec: dict, workload: str, traced: bool) -> list:
             if workload in m.get("workloads", [workload])]
 
 
-def read_metric(name: str, ctx: dict):
-    path = os.path.join(BENCH, "metrics", name + ".py")
+def load_module(kind: str, path: str):
+    name = os.path.splitext(os.path.basename(path))[0]
     mod_spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_"),
+        path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return mod
+
+
+def read_metric(name: str, ctx: dict):
+    return load_module("metric", os.path.join(BENCH, "metrics",
+                                              name + ".py")).read(ctx)
+
+
+def load_model(name: str):
+    """The module ``<name>.py`` in :data:`MODELS`: everything of a model
+    that the harness does not share.  It provides
+
+    * ``init(words, cfg) -> leaves``: the reference's float32 weights, a
+      flat list in a fixed order, from a key's words
+      (:func:`chipbench.reference.seed_words`); jitted by the caller;
+    * ``to_program(leaves)`` and ``from_program(params)``: the program's
+      parameter tree to and from those leaves;
+    * ``trainer_kwargs(cfg) -> dict``: what the program's ``Trainer`` is
+      told about the model;
+    * ``loss_and_grad(leaves, batch, data, precision, keep)``: the plain
+      reference's mean loss over the first ``keep`` share of the batch's
+      roots and its gradient per leaf, its products computed at
+      ``precision`` by :mod:`chipbench.reference`'s shared ones;
+    * ``output_leaves(n) -> list[bool]``: which of ``n`` leaves are the
+      output layer's, the leaves ``grad_norm_gap`` compares;
+    * ``step_work(batch, cfg) -> {"ell_ops", "ell_bytes", "model_ops"}``:
+      the operations and bytes one step on ``batch`` requires.
+
+    The loss is a softmax cross-entropy over single labels, since the
+    benchmark's data (``chipbench/data.py``) makes one class per node; a
+    multilabel model would first need multilabel data there."""
+    path = os.path.join(MODELS, name + ".py")
+    if not os.path.isfile(path):
+        have = sorted(f[:-3] for f in os.listdir(MODELS)
+                      if f.endswith(".py"))
+        raise SystemExit(f"unknown model {name!r}; models: {have}")
+    mod = load_module("model", path)
+    missing = [f for f in MODEL_FUNCTIONS
+               if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SystemExit(f"model {name!r} lacks {missing}")
+    return mod
 
 
 def finite(x: float) -> float:
@@ -192,6 +240,7 @@ def run(argv=None, *, require_chip: bool = True, step_hook=None,
     cell, cfg, traffic = files["cell"], files["cfg"], files["traffic"]
     if traffic["kind"] != "sampled_train":
         raise SystemExit(f"traffic kind {traffic['kind']!r} has no runner here")
+    model = load_model(cfg["model"])
     traced = bool(args.trace)
     if require_chip and not args.prime and not prime(args.workload,
                                                      args.seed):
@@ -200,11 +249,15 @@ def run(argv=None, *, require_chip: bool = True, step_hook=None,
         return 1
 
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
     jax.config.update("jax_enable_compilation_cache", True)
     jax.config.update("jax_compilation_cache_dir", cache_dir())
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_compilation_cache_max_size", -1)
+    # a cache this process opened before (another run in it) keeps its
+    # directory until it is reset
+    compilation_cache.reset_cache()
     devs = find_chips(cell["chips"], require_chip)
     if devs is None:
         return 1
@@ -214,21 +267,19 @@ def run(argv=None, *, require_chip: bool = True, step_hook=None,
         print(f"run: no peaks for device kind {kind!r} in peaks.json",
               file=sys.stderr)
         return 1
-    from jax.experimental.compilation_cache import compilation_cache
-
     from repro.kernels import tune
 
     from chipbench import compare, reference
     from chipbench.data import make_graph
-    from chipbench.train import CompileCounter, TrainCell, dims_io
+    from chipbench.train import CompileCounter, TrainCell
 
     tune.pin(tune.DEFAULTS["tpu"])
     counter = CompileCounter()
     t0 = time.perf_counter()
     data = make_graph(cfg)
     t1 = time.perf_counter()
-    tc = TrainCell(cfg, traffic, cell["chips"], data, annotate=traced,
-                   step_hook=step_hook)
+    tc = TrainCell(cfg, traffic, cell["chips"], data, model,
+                   annotate=traced, step_hook=step_hook)
     tc.reseed(args.seed)
     t2 = time.perf_counter()
     prog = tc.warm(traffic["compared_steps"])
@@ -272,11 +323,13 @@ def run(argv=None, *, require_chip: bool = True, step_hook=None,
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
     t0 = time.perf_counter()
-    ref = reference.sgd_steps(prog["w0"], prog["batches"], data, lr)
+    ref = reference.sgd_steps(model.loss_and_grad, prog["w0"],
+                              prog["batches"], data, lr)
     print(f"run: window {len(win['losses'])} steps in {win['seconds']:.3f}s,"
           f" compiles {window_compiles}; reference "
           f"{time.perf_counter() - t0:.2f}s", file=sys.stderr)
-    values = compare.numbers(prog, ref, lr)
+    values = compare.numbers(prog, ref, lr,
+                             model.output_leaves(len(prog["w0"])))
     failed = sum(not math.isfinite(x) for x in win["losses"])
     values["window_failed_steps"] = failed
     correct, checks = compare.judge(
@@ -288,7 +341,7 @@ def run(argv=None, *, require_chip: bool = True, step_hook=None,
            "setup_programs": setup_counts[0],
            "distinct_batches": traffic["distinct_batches"],
            "peaks": peaks.get(kind),
-           "dims_io": dims_io(cfg),
+           "model": model,
            "trace": None}
     device = {"platform": devs[0].platform, "kind": kind, "count": n_chips,
               "memory_peak_bytes": peak}

@@ -108,24 +108,19 @@ class Replay:
         self.i = int(state.get("i", 0))
 
 
-def dims_io(cfg: dict) -> list:
-    widths = [cfg["feat_dim"]] + [cfg["hidden"]] * (cfg["n_layers"] - 1) \
-        + [cfg["n_classes"]]
-    return list(zip(widths[:-1], widths[1:]))
-
-
 def seed_order(seed: int, n: int) -> list:
     return [int(i) for i in np.random.default_rng(int(seed)).permutation(n)]
 
 
 class TrainCell:
     """The program's Trainer on the configuration's graph, driven as the
-    traffic says.  ``step_hook(cell, params, batch) -> (params, loss)``,
-    when given, takes the place of the engine's step (tests plant faults
-    with it)."""
+    traffic says; ``model`` is the configuration's model module
+    (:func:`chipbench.run.load_model`).  ``step_hook(cell, params, batch)
+    -> (params, loss)``, when given, takes the place of the engine's step
+    (tests plant faults with it)."""
 
     def __init__(self, cfg: dict, traffic: dict, chips: int, data: dict,
-                 annotate: bool = False, step_hook=None):
+                 model, annotate: bool = False, step_hook=None):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
@@ -134,10 +129,7 @@ class TrainCell:
         from repro.graph.sampler import CSRGraph
         from repro.launch.trainer import Trainer
 
-        from chipbench import reference
-
-        self.data, self.annotate = data, annotate
-        self.dims_io = dims_io(cfg)
+        self.data, self.model, self.annotate = data, model, annotate
         if cfg["n_layers"] != len(traffic["fanouts"]):
             raise ValueError("one sampled hop per layer: n_layers "
                              f"{cfg['n_layers']} vs fanouts "
@@ -154,7 +146,8 @@ class TrainCell:
             batch_size=traffic["batch_size"], fanouts=traffic["fanouts"],
             lr=cfg["lr"], seed=traffic["batch_seed"],
             prefetch_depth=traffic["prefetch_depth"],
-            pad_multiple=traffic["pad_multiple"])
+            pad_multiple=traffic["pad_multiple"],
+            **model.trainer_kwargs(cfg))
         self.lr = float(tr.engine.config.lr)
         self.replay = Replay(tr.pipeline, range(traffic["distinct_batches"]),
                              annotate)
@@ -171,7 +164,7 @@ class TrainCell:
         if step_hook is not None:
             tr.bundle.train_step = lambda p, b: step_hook(self, p, b)
         self._init = jax.jit(
-            lambda k: reference.init_params(k, self.dims_io),
+            lambda k: model.init(k, cfg),
             out_shardings=NamedSharding(tr.mesh, PartitionSpec()))
         self.devices = list(tr.mesh.devices.flat)
 
@@ -182,13 +175,14 @@ class TrainCell:
 
         ws0 = self._init(reference.seed_words(seed))
         self.w0 = [np.asarray(w) for w in ws0]
-        self.trainer.params = [{"w": w} for w in ws0]
+        self.trainer.params = self.model.to_program(ws0)
         self.replay.order = seed_order(seed, len(self.replay.order))
         self.trainer.fetcher.restore({"i": 0})
         self.steps_done = 0
 
     def weights(self) -> list:
-        return [np.asarray(p["w"]) for p in self.trainer.params]
+        return [np.asarray(w)
+                for w in self.model.from_program(self.trainer.params)]
 
     def step(self) -> float:
         loss = self.trainer.train_steps(1)[0]

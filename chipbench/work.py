@@ -1,5 +1,7 @@
 """Operations and bytes that one training step of the GCN requires, counted
-from the batch's real rows and edges, never from padded shapes.
+from the batch's real rows and edges, never from padded shapes (the GCN
+model's ``step_work``, ``chipbench/models/gcn.py``), and their sum over a
+window for any model.
 
 A batch is the structure the benchmark records from the sampler (see
 :func:`chipbench.reference.batch_inputs`): per hop, shallowest first, the
@@ -54,15 +56,20 @@ def step_work(batch: dict, dims_io) -> dict:
             "model_ops": model_ops}
 
 
-def window_work(batches: list, dims_io) -> dict:
-    """Summed :func:`step_work` over the window's steps (``batches`` holds
-    one batch per step, repeats included)."""
-    per = {}
-    total = {"ell_ops": 0, "ell_bytes": 0, "model_ops": 0}
+def window_total(batches: list, count) -> dict:
+    """``count(batch)``, a dict of numbers, summed over the window's steps
+    (``batches`` holds one batch per step, repeats included; each distinct
+    batch is counted once)."""
+    per, total = {}, {}
     for b in batches:
         key = id(b)
         if key not in per:
-            per[key] = step_work(b, dims_io)
+            per[key] = count(b)
         for k, v in per[key].items():
-            total[k] += v
+            total[k] = total.get(k, 0) + v
     return total
+
+
+def window_work(batches: list, dims_io) -> dict:
+    """Summed :func:`step_work` over the window's steps."""
+    return window_total(batches, lambda b: step_work(b, dims_io))
